@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import load_checkpoint, save_checkpoint
+from repro.common.compile_cache import enable_compile_cache  # noqa: F401
 from repro.configs.dit_moe_xl import config as xl_config, tiny
 from repro.configs.dit_moe_g import config as g_config
 from repro.core.schedules import DiceConfig

@@ -34,6 +34,7 @@ def run(num_steps: int = 20):
 
 
 def main():
+    common.enable_compile_cache()
     run()
 
 

@@ -56,6 +56,7 @@ def run():
 
 
 def main():
+    common.enable_compile_cache()
     run()
 
 

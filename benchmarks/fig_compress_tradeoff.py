@@ -128,6 +128,8 @@ def main():
         os.environ.setdefault("BENCH_TRAIN_STEPS", "40")
         os.environ.setdefault("BENCH_SAMPLES", "16")
         os.environ.setdefault("BENCH_SMOKE", "1")
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run(num_steps=args.steps)
     print("OK: compressed wire < lossless wire, raw == lossless, "

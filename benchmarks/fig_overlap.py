@@ -171,6 +171,8 @@ def main():
     args = ap.parse_args()
     if args.smoke:
         os.environ.setdefault("BENCH_SMOKE", "1")
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run()
     print("OK: modeled ring < blocking in the comm-bound regime; "

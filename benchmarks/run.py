@@ -3,7 +3,7 @@
 Prints ``name,us_per_call,derived`` CSV rows.  Quality metrics are
 FID-proxy / paired-MSE on synthetic latents (ordering is the validated
 claim); latency/speedup numbers are modeled on the paper's 8-device setup
-from roofline terms (no TPU in this container).  See EXPERIMENTS.md.
+from roofline terms, never measured.  See EXPERIMENTS.md.
 
   PYTHONPATH=src python -m benchmarks.run [--only table1,table5] [--fast]
 """
@@ -148,6 +148,8 @@ def main() -> None:
         os.environ.setdefault("BENCH_TRAIN_STEPS", "60")
         os.environ.setdefault("BENCH_SAMPLES", "32")
         os.environ.setdefault("BENCH_SMOKE", "1")
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mods = args.only.split(",") if args.only else TABLES
     print("name,us_per_call,derived")
     for name in mods:

@@ -502,6 +502,7 @@ def main():
                          "a bounded quality delta; writes "
                          "BENCH_serve_chaos.json")
     args = ap.parse_args()
+    common.enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 12)
         args.steps = min(args.steps, 4)

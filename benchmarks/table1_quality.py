@@ -36,6 +36,7 @@ def run(num_steps: int = 50, label: str = "table1"):
 
 
 def main():
+    common.enable_compile_cache()
     run()
 
 

@@ -49,6 +49,7 @@ def run():
 
 
 def main():
+    common.enable_compile_cache()
     run()
 
 

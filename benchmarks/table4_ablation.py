@@ -62,6 +62,7 @@ def run(num_steps: int = 50):
 
 
 def main():
+    common.enable_compile_cache()
     run()
 
 

@@ -58,6 +58,7 @@ def run():
 
 
 def main():
+    common.enable_compile_cache()
     run()
 
 

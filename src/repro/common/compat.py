@@ -1,42 +1,19 @@
-"""Version-compatibility shims for jax APIs that moved between releases.
+"""Mesh helpers written against the installed jax (0.9).
 
-The container pins jax 0.4.37 while CI installs the latest release; the
-two disagree on where ``shard_map`` lives and whether meshes carry
-explicit axis types.  Route every use through here so the rest of the
-code is version-agnostic.
+``shard_map`` and ``axis_size`` are the public ``jax`` spellings; every
+use goes through here so a future API move is a one-file change.
 """
 from __future__ import annotations
 
 import jax
 
-try:
-    shard_map = jax.shard_map                     # jax >= 0.6
-except AttributeError:                            # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, **kwargs):
-        # the legacy replication checker lacks rules for some primitives
-        # we use (e.g. checkpoint_name); the new API dropped the check
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_old(f, **kwargs)
-
-
-def axis_size(axis_name) -> int:
-    """Size of a named mesh axis from inside shard_map.  Newer jax has
-    ``jax.lax.axis_size``; older releases constant-fold ``psum(1, axis)``
-    to the same integer."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:                    # pragma: no cover
-        return jax.lax.psum(1, axis_name)
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 def make_mesh(axis_shapes, axis_names, **kwargs) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them
-    (newer jax), silently dropping the argument where it does not."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs.setdefault("axis_types", (axis_type.Auto,) * len(axis_shapes))
-    else:
-        kwargs.pop("axis_types", None)
+    """``jax.make_mesh`` with Auto axis types: the serving stack places
+    its arrays with explicit ``NamedSharding``s and ``shard_map``."""
+    kwargs.setdefault("axis_types",
+                      (jax.sharding.AxisType.Auto,) * len(axis_shapes))
     return jax.make_mesh(axis_shapes, axis_names, **kwargs)

@@ -18,8 +18,9 @@ def config() -> ModelConfig:
 
 
 def smoke() -> ModelConfig:
+    """CPU test size, in float32."""
     return config().replace(
         name="dit-moe-g-smoke", num_layers=2, d_model=128, d_ff=256,
         num_heads=4, num_kv_heads=4, head_dim=32, num_experts=4,
         experts_per_token=2, num_shared_experts=1, moe_d_ff=128,
-        patch_tokens=16, num_classes=8, in_channels=4)
+        patch_tokens=16, num_classes=8, in_channels=4, dtype="float32")

@@ -20,18 +20,20 @@ def config() -> ModelConfig:
 
 
 def smoke() -> ModelConfig:
+    """CPU test size, in float32."""
     return config().replace(
         name="dit-moe-smoke", num_layers=2, d_model=128, d_ff=256,
         num_heads=4, num_kv_heads=4, head_dim=32, num_experts=4,
         experts_per_token=2, num_shared_experts=1, moe_d_ff=128,
-        patch_tokens=16, num_classes=8, in_channels=4)
+        patch_tokens=16, num_classes=8, in_channels=4, dtype="float32")
 
 
 def tiny() -> ModelConfig:
-    """CPU-trainable variant for the quality experiments (benchmarks)."""
+    """CPU-trainable variant for the quality experiments (benchmarks),
+    in float32."""
     return config().replace(
         name="dit-moe-tiny", num_layers=6, d_model=96, d_ff=384,
         num_heads=4, num_kv_heads=4, head_dim=24, num_experts=8,
         experts_per_token=2, num_shared_experts=2, moe_d_ff=96,
         patch_tokens=64, num_classes=8, in_channels=4,
-        capacity_factor=1.5)
+        capacity_factor=1.5, dtype="float32")

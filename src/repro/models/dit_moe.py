@@ -49,7 +49,10 @@ def timestep_embedding(t, dim: int = 256):
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
-def init_dit(key, cfg: ModelConfig, *, dtype=jnp.float32) -> Dict[str, Any]:
+def init_dit(key, cfg: ModelConfig) -> Dict[str, Any]:
+    """Weights in ``cfg.dtype`` (bf16 for the published configs); norm
+    scales and the router stay f32."""
+    dtype = jnp.dtype(cfg.dtype)
     d, c_in = cfg.d_model, cfg.in_channels
     keys = jax.random.split(key, cfg.num_layers + 6)
     params: Dict[str, Any] = {

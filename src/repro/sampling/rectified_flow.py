@@ -156,6 +156,10 @@ def make_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
     :class:`ObsConfig` adds the ``"telemetry"`` aux output (DESIGN.md
     Sec. 16) without becoming a jit argument, so the cache contract (one
     entry per (plan, slotted) pair) is unchanged either way.
+
+    ``params`` are never a closure constant: the jitted step takes them
+    as its first argument and the returned :class:`BoundStep` passes
+    them in on every call (see there).
     """
     if mesh is not None:
         return _make_mesh_rf_step(
@@ -165,8 +169,8 @@ def make_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
             expert_pool=expert_pool, obs=obs)
 
     @partial(jax.jit, static_argnames=("plan", "slotted"))
-    def rf_step(x, classes, states, states_u, patch_states, patch_states_u,
-                t, key, *, plan, slotted=False,
+    def rf_step(params, x, classes, states, states_u, patch_states,
+                patch_states_u, t, key, *, plan, slotted=False,
                 slot_fresh=None, consume_mask=None, patch_fresh=None):
         del patch_fresh                # mesh-only selector; inert here
         return _euler_step(
@@ -178,7 +182,33 @@ def make_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
             consume_mask=consume_mask if slotted else None,
             obs=obs)
 
-    return rf_step
+    return BoundStep(rf_step, params)
+
+
+class BoundStep:
+    """A jitted step whose first argument is the weight tree, with that
+    tree bound: ``step(x, ...)`` calls ``jitted(params, x, ...)``.
+
+    jax embeds the arrays a jitted function closes over as literal
+    constants of its compiled program — at DiT-MoE-XL size ~10 GB per
+    plan variant, compiled and held on the device again beside the
+    weights themselves.  Passed as an argument, the weights stay one
+    set of device buffers that every variant reads.  ``lower`` and
+    ``_cache_size`` forward to the jitted function.
+    """
+
+    def __init__(self, jitted, params):
+        self.jitted = jitted
+        self.params = params
+
+    def __call__(self, *args, **kwargs):
+        return self.jitted(self.params, *args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        return self.jitted.lower(self.params, *args, **kwargs)
+
+    def _cache_size(self) -> int:
+        return self.jitted._cache_size()
 
 
 def _make_mesh_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
@@ -252,8 +282,8 @@ def _make_mesh_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
     pspecs = shard_lib.ep_param_specs(params, ep_axis=live_ep)
 
     @partial(jax.jit, static_argnames=("plan", "slotted"))
-    def rf_step(x, classes, states, states_u, patch_states, patch_states_u,
-                t, key, *, plan, slotted=False,
+    def rf_step(params, x, classes, states, states_u, patch_states,
+                patch_states_u, t, key, *, plan, slotted=False,
                 slot_fresh=None, consume_mask=None, patch_fresh=None):
         if x.shape[0] % max(n_batch, 1):
             raise ValueError(f"batch {x.shape[0]} must divide over the "
@@ -314,13 +344,20 @@ def _make_mesh_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
             aux = dict(aux, buffer_bytes=jnp.asarray(aux["buffer_bytes"]))
             return x_new, ns, nsu, nps, npsu, aux
 
+        # The patch KV buffers leave ``sharded_patch_attention`` as the
+        # tiled ``all_gather`` over the patch axis, so they are identical
+        # on every device of a patch group by construction.  jax types an
+        # all_gather result as varying over the gathered axis, so the
+        # replication check cannot infer the ``P(b_dim)`` out_spec; it
+        # is turned off only on meshes that carry a patch axis.
         x_new, ns, nsu, nps, npsu, aux = compat.shard_map(
             inner, mesh=mesh, in_specs=in_specs,
             out_specs=(x_spec, st_spec, stu_spec, pst_spec, pstu_spec,
-                       aux_spec))(*ops)
+                       aux_spec),
+            check_vma=patch_axis is None)(*ops)
         return x_new, ns, nsu, nps, npsu, aux
 
-    return rf_step
+    return BoundStep(rf_step, params)
 
 
 def make_sample_step(params, cfg: ModelConfig, dcfg: DiceConfig, classes, *,
