@@ -107,6 +107,7 @@ def expected_dispatch_fraction(k: int, stride: int, policy: str,
     return (c_full + (stride - 1) * c_light) / (stride * c_full)
 
 
+@jax.named_scope("stale_select")
 def update_cache(h_cache: Optional[jnp.ndarray],
                  pair_vals: jnp.ndarray,
                  mask: Optional[jnp.ndarray]) -> jnp.ndarray:

@@ -85,6 +85,7 @@ class DispatchPlan(NamedTuple):
     counts: jnp.ndarray      # (E,) tokens routed per expert (pre-drop)
 
 
+@jax.named_scope("router")
 def route(p, x, cfg: ModelConfig, *, key=None):
     """Router probabilities + top-k selection.  x: (T, d).
 
@@ -103,6 +104,7 @@ def route(p, x, cfg: ModelConfig, *, key=None):
     return probs, scores, idx
 
 
+@jax.named_scope("dispatch")
 def make_plan(idx, E: int, capacity: int,
               fresh_mask: Optional[jnp.ndarray] = None,
               num_slots: Optional[int] = None) -> DispatchPlan:
@@ -136,6 +138,7 @@ def make_plan(idx, E: int, capacity: int,
                         counts=counts)
 
 
+@jax.named_scope("dispatch")
 def dispatch(x, plan: DispatchPlan, E: int, capacity: int):
     """Scatter tokens into the (E, C, d) dispatch buffer."""
     d = x.shape[-1]
@@ -145,6 +148,7 @@ def dispatch(x, plan: DispatchPlan, E: int, capacity: int):
     return buf.reshape(E, capacity, d)
 
 
+@jax.named_scope("combine")
 def combine(buf_out, plan: DispatchPlan, scores, T: int, *,
             h_cache: Optional[jnp.ndarray] = None,
             fresh_mask: Optional[jnp.ndarray] = None):
@@ -176,6 +180,7 @@ def combine(buf_out, plan: DispatchPlan, scores, T: int, *,
 # ---------------------------------------------------------------------------
 # expert FFN (grouped, gated) — jnp reference; Pallas kernel in repro.kernels
 # ---------------------------------------------------------------------------
+@jax.named_scope("expert_ffn")
 def expert_ffn(p, buf, *, act: str = "silu", use_pallas: bool = False):
     """buf: (E_local, C, d) -> (E_local, C, d)."""
     if use_pallas:
@@ -188,6 +193,7 @@ def expert_ffn(p, buf, *, act: str = "silu", use_pallas: bool = False):
     return jnp.einsum("ecf,efd->ecd", h, p["experts_down"])
 
 
+@jax.named_scope("shared_ffn")
 def shared_expert(p, x, *, act: str = "silu"):
     fn = jax.nn.silu if act == "silu" else jax.nn.gelu
     return (fn(x @ p["shared_gate"]) * (x @ p["shared_up"])) @ p["shared_down"]
@@ -196,6 +202,7 @@ def shared_expert(p, x, *, act: str = "silu"):
 # ---------------------------------------------------------------------------
 # load-balance aux loss (switch-style)
 # ---------------------------------------------------------------------------
+@jax.named_scope("router")
 def load_balance_loss(probs, idx, E: int, ep_axis=None):
     """Switch-style aux loss.  ``ep_axis`` is the axis (or tuple of axes
     — the hierarchical dp x ep x patch mesh shards tokens over several)
@@ -497,8 +504,10 @@ def moe_forward(p, x, cfg: ModelConfig, *,
             # collectives to f32 in the lowered HLO; on TPU the wire dtype is
             # bf16 (repro.launch.hlo_cost applies the bf16-wire correction).
             b = buf.reshape(n, e_loc, capacity, d)
-            b = jax.lax.all_to_all(b, ep_axis, split_axis=0, concat_axis=0,
-                                   tiled=True)                  # (n, e_loc, C, d)
+            with jax.named_scope("dispatch"):
+                b = jax.lax.all_to_all(b, ep_axis, split_axis=0,
+                                       concat_axis=0,
+                                       tiled=True)      # (n, e_loc, C, d)
             # named so remat policies can keep the received buffer and avoid
             # re-running the dispatch all-to-all during the backward pass
             b = jax.ad_checkpoint.checkpoint_name(b, "ep_recv")
@@ -506,8 +515,10 @@ def moe_forward(p, x, cfg: ModelConfig, *,
             b = expert_ffn(local, b, act=cfg.act, use_pallas=use_pallas)
             # ---- combine all-to-all (collective #2) ----------------------
             b = jnp.moveaxis(b.reshape(e_loc, n, capacity, d), 1, 0)
-            b = jax.lax.all_to_all(b.astype(x.dtype), ep_axis, split_axis=0,
-                                   concat_axis=0, tiled=True)
+            with jax.named_scope("combine"):
+                b = jax.lax.all_to_all(b.astype(x.dtype), ep_axis,
+                                       split_axis=0, concat_axis=0,
+                                       tiled=True)
             buf_out = b.reshape(S, capacity, d)
             if loc_ffn is not None:
                 loc_out = loc_ffn()

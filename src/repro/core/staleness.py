@@ -289,6 +289,7 @@ def apply_layer_action(p, x, cfg: ModelConfig, action: LayerAction,
             return payload
         return state.c_base
 
+    @jax.named_scope("stale_select")
     def select_out(y_new, y_buf):
         """Consumed output: warmup-slot tokens take the fresh combine."""
         if slot_fresh is None:
@@ -310,8 +311,9 @@ def apply_layer_action(p, x, cfg: ModelConfig, action: LayerAction,
         # experts process tokens dispatched at s-1; their combine lands at s+1,
         # so the output consumed *now* is the buffered result of x(s-2).
         # Warmup slots run sync: their experts see x(s), and they consume it.
-        inp = state.x_prev if slot_fresh is None else \
-            jnp.where(slot_fresh[:, None], x, state.x_prev)
+        with jax.named_scope("stale_select"):
+            inp = state.x_prev if slot_fresh is None else \
+                jnp.where(slot_fresh[:, None], x, state.x_prev)
         y_new, aux = run(inp)
         out = select_out(y_new, state.y_buf)
         new = MoELayerState(y_buf=y_new, x_prev=x, h_cache=None,

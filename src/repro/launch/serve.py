@@ -303,9 +303,6 @@ def _publish_batch(reg: MetricsRegistry, stats: dict, lab: dict) -> None:
               lab).set_max(int(stats["buffer_bytes"]))
     reg.counter("dice_dispatch_bytes_total", "dispatch payload moved",
                 lab).inc(float(sum(stats["dispatch_bytes_per_step"])))
-    reg.counter("dice_wire_bytes_total",
-                "codec-compressed bytes on the wire",
-                lab).inc(stats["wire_bytes_total"])
     reg.counter("dice_raw_bytes_total", "lossless-equivalent payload bytes",
                 lab).inc(stats["raw_bytes_total"])
     reg.gauge("dice_ring_hops", "ring collective-permutes per MoE layer",
@@ -385,7 +382,8 @@ def _registry_view(reg: MetricsRegistry, lab: dict) -> dict:
         "a2a_bytes_per_layer": reg.value("dice_a2a_bytes_per_layer", lab),
         "buffer_bytes": int(reg.value("dice_buffer_bytes", lab)),
         "dispatch_bytes_total": reg.value("dice_dispatch_bytes_total", lab),
-        "wire_bytes_total": reg.value("dice_wire_bytes_total", lab),
+        # the dispatch payload IS the wire payload (codec-compressed)
+        "wire_bytes_total": reg.value("dice_dispatch_bytes_total", lab),
         "raw_bytes_total": reg.value("dice_raw_bytes_total", lab),
         "ring_hops": int(reg.value("dice_ring_hops", lab)),
         "hop_bytes_total": reg.value("dice_hop_bytes_total", lab),
@@ -621,6 +619,23 @@ class DiceServer:
         return samples, result
 
 
+def _span(tracer: Optional[StepTracer], name: str,
+          args: Optional[dict] = None, cat: str = "serve"):
+    """``tracer.span(name, cat, args)``, or a no-op context without a
+    tracer.  The span reads ``args`` as it ends, so the block may fill
+    it in."""
+    if tracer is None:
+        return contextlib.nullcontext()
+    return tracer.span(name, cat=cat, args=args)
+
+
+def _read(a, rb: dict) -> np.ndarray:
+    """One blocking device->host read of a tick's aux, counted in the
+    ``reads`` arg of its ``serve.readback`` span."""
+    rb["reads"] += 1
+    return np.asarray(a)
+
+
 # ---------------------------------------------------------------------------
 # batched serving loop (FIFO queue -> fixed-size compiled batches)
 # ---------------------------------------------------------------------------
@@ -655,10 +670,8 @@ def serve_queue(server: "DiceServer", requests: List[Request], *,
         padded = batch + [Request(class_id=server.cfg.num_classes,
                                   rid=-1)] * pad
         key, k = jax.random.split(key)
-        span = (tracer.span("serve_queue_batch", cat="serve",
-                            args={"batch": len(batch), "pad": pad})
-                if tracer is not None else contextlib.nullcontext())
-        with span:
+        with _span(tracer, "serve_queue_batch",
+                   {"batch": len(batch), "pad": pad}):
             samples, _ = server.generate(padded, num_steps=num_steps,
                                          guidance=guidance, key=k,
                                          metrics=reg, metric_labels=lab)
@@ -746,6 +759,16 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     re-placed with ``staleness.shard_states`` so the jitted step always
     sees one stable input layout, and the compile-count guarantee (jit
     cache == plan-variant count) carries over to the sharded path.
+
+    With ``server.tracer`` set, every tick's host work is spanned, in
+    order: ``serve.admit`` (a tick that admits; args ``tick``,
+    ``admitted``), ``serve.prepare`` (plan, slot masks, step inputs), the
+    ``tick`` span around ``serve.dispatch`` (the step's launch) and
+    ``serve.wait`` (``block_until_ready``), ``serve.readback`` (the aux
+    read-back and what consumes it; ``reads`` counts its blocking
+    device->host reads), ``serve.quarantine`` (resilience on) and
+    ``serve.complete`` (a tick where a request finishes).  A request's
+    ``admit`` and ``done`` instants share its ``rid``.
     """
     cfg, dcfg = server.cfg, server.dcfg
     mesh = mesh if mesh is not None else server.mesh
@@ -830,11 +853,9 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
         drift-triggered re-shard swaps ``dcfg.placements`` and rebuilds —
         always from ``server.params`` (the ORIGINAL, identity-layout
         tree), which ``_make_mesh_rf_step`` re-lays-out per placement."""
-        span = (tracer.span("plan_build", cat="plan",
-                            args={"schedule": lab["schedule"],
-                                  "num_steps": num_steps})
-                if tracer is not None else contextlib.nullcontext())
-        with span:
+        with _span(tracer, "plan_build", {"schedule": lab["schedule"],
+                                          "num_steps": num_steps},
+                   cat="plan"):
             splan = plan_lib.compile_step_plans(
                 dcfg, cfg.num_layers, num_steps, experts_per_token=k_exp)
             merge_plan = plan_lib.slotted_merge_plan(
@@ -965,50 +986,62 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
         # ---- admission at plan-variant-aligned boundaries ----------------
         if tick % period == 0:
             recycle = np.zeros(B, bool)
-            for i, slot in enumerate(slots):
-                if slot.active:
-                    continue
-                req = queue.pop_ready(tick)
-                if req is None:
-                    break
-                slots[i] = _Slot(rid=req.rid, class_id=req.class_id,
-                                 local_step=0, active=True)
-                recycle[i] = True
-                classes[i] = req.class_id
-                x = x.at[i].set(request_noise(noise_key, req.rid, cfg))
-                reg.counter("dice_admissions_total", "slot admissions",
-                            lab).inc()
-                if ever_used[i]:
-                    reg.counter("dice_recycled_admissions_total",
-                                "admissions into a recycled slot",
+            # the span marks a tick that admits: a lane is free and a
+            # request has arrived (what pop_ready below tests)
+            nxt = queue.next_arrival()
+            admitting = (nxt is not None and nxt <= tick
+                         and not all(s.active for s in slots))
+            adm = {"tick": tick, "admitted": 0}
+            with _span(tracer if admitting else None, "serve.admit", adm):
+                for i, slot in enumerate(slots):
+                    if slot.active:
+                        continue
+                    req = queue.pop_ready(tick)
+                    if req is None:
+                        break
+                    slots[i] = _Slot(rid=req.rid, class_id=req.class_id,
+                                     local_step=0, active=True)
+                    recycle[i] = True
+                    classes[i] = req.class_id
+                    x = x.at[i].set(request_noise(noise_key, req.rid, cfg))
+                    reg.counter("dice_admissions_total", "slot admissions",
                                 lab).inc()
-                if tracer is not None:
-                    tracer.instant("admit", args={
-                        "rid": req.rid, "slot": i, "tick": tick,
-                        "recycled": bool(ever_used[i])})
-                admit_time[req.rid] = time.perf_counter()
-                ever_used[i] = True
-            # load shedding (Sec. 17 rung 5): only when a depth bound or
-            # admission deadline is configured — a no-op ([], peak-depth
-            # bookkeeping only) on the unbounded default
-            for rid in queue.shed_overdue(tick, retry_after=float(period)):
-                reg.counter("dice_shed_requests_total",
-                            "requests shed by admission bounds", lab).inc()
-                if tracer is not None:
-                    tracer.instant("shed", args={"rid": rid, "tick": tick})
-            if recycle.any():
-                m = jnp.asarray(recycle)
-                states = stale_lib.reset_slots(states, m, tokens_per_slot=Tp)
-                states_u = stale_lib.reset_slots(states_u, m,
-                                                 tokens_per_slot=Tp)
-                if mesh is not None:
-                    # re-place after host-side surgery: a drifted layout
-                    # would key extra jit-cache entries
-                    states = stale_lib.shard_states(states, mesh,
-                                                    ep_axis=b_dim)
-                    states_u = stale_lib.shard_states(states_u, mesh,
-                                                      ep_axis=b_dim)
-                    x = _place(x)
+                    if ever_used[i]:
+                        reg.counter("dice_recycled_admissions_total",
+                                    "admissions into a recycled slot",
+                                    lab).inc()
+                    if tracer is not None:
+                        tracer.instant("admit", args={
+                            "rid": req.rid, "slot": i, "tick": tick,
+                            "recycled": bool(ever_used[i])})
+                    admit_time[req.rid] = time.perf_counter()
+                    ever_used[i] = True
+                    adm["admitted"] += 1
+                # load shedding (Sec. 17 rung 5): only when a depth bound or
+                # admission deadline is configured — a no-op ([], peak-depth
+                # bookkeeping only) on the unbounded default
+                for rid in queue.shed_overdue(tick,
+                                              retry_after=float(period)):
+                    reg.counter("dice_shed_requests_total",
+                                "requests shed by admission bounds",
+                                lab).inc()
+                    if tracer is not None:
+                        tracer.instant("shed", args={"rid": rid,
+                                                     "tick": tick})
+                if recycle.any():
+                    m = jnp.asarray(recycle)
+                    states = stale_lib.reset_slots(states, m,
+                                                   tokens_per_slot=Tp)
+                    states_u = stale_lib.reset_slots(states_u, m,
+                                                     tokens_per_slot=Tp)
+                    if mesh is not None:
+                        # re-place after host-side surgery: a drifted
+                        # layout would key extra jit-cache entries
+                        states = stale_lib.shard_states(states, mesh,
+                                                        ep_axis=b_dim)
+                        states_u = stale_lib.shard_states(states_u, mesh,
+                                                          ep_axis=b_dim)
+                        x = _place(x)
         if not any(s.active for s in slots):
             nxt = queue.next_arrival()
             if nxt is None:
@@ -1018,45 +1051,49 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
             continue
 
         # ---- one engine tick --------------------------------------------
-        warming = [s.active and s.local_step < dcfg.warmup_steps
-                   for s in slots]
-        slotted = any(warming)
-        if slotted:
-            plan = merge_plan
-            # free slots replay warmup too: their (discarded) lanes then
-            # consume only fresh values, never the zeroed buffers
-            fresh_b = np.array([w or not s.active
-                                for w, s in zip(warming, slots)])
-            slot_fresh = jnp.repeat(jnp.asarray(fresh_b), Tp)
-            consume = None
-            if merge_wants_cache:
-                light = dcfg.cond_comm and not conditional.is_refresh_step(
-                    tick, dcfg.cond_stride)
-                if light:
-                    steady_mask = conditional.policy_mask(
-                        dcfg.cond_policy, B * Tp, k_exp,
-                        key=jax.random.fold_in(step_key, tick))
-                else:
-                    steady_mask = jnp.ones((B * Tp, k_exp), bool)
-                consume = jnp.where(slot_fresh[:, None], True, steady_mask)
-        else:
-            ref = min(s.local_step for s in slots if s.active)
-            plan_idx = min(ref, num_steps - 1)
-            plan = splan.steps[plan_idx]
-            slot_fresh = consume = None
+        with _span(tracer, "serve.prepare", {"tick": tick}):
+            warming = [s.active and s.local_step < dcfg.warmup_steps
+                       for s in slots]
+            slotted = any(warming)
+            tick_key = jax.random.fold_in(step_key, tick)
+            if slotted:
+                plan = merge_plan
+                # free slots replay warmup too: their (discarded) lanes
+                # then consume only fresh values, never the zeroed buffers
+                fresh_b = np.array([w or not s.active
+                                    for w, s in zip(warming, slots)])
+                slot_fresh = jnp.repeat(jnp.asarray(fresh_b), Tp)
+                consume = None
+                if merge_wants_cache:
+                    light = dcfg.cond_comm and not conditional.is_refresh_step(
+                        tick, dcfg.cond_stride)
+                    if light:
+                        steady_mask = conditional.policy_mask(
+                            dcfg.cond_policy, B * Tp, k_exp, key=tick_key)
+                    else:
+                        steady_mask = jnp.ones((B * Tp, k_exp), bool)
+                    consume = jnp.where(slot_fresh[:, None], True,
+                                        steady_mask)
+            else:
+                ref = min(s.local_step for s in slots if s.active)
+                plan_idx = min(ref, num_steps - 1)
+                plan = splan.steps[plan_idx]
+                slot_fresh = consume = None
+            t = jnp.asarray([s.local_step * dt if s.active else 0.0
+                             for s in slots], jnp.float32)
+            cls = jnp.asarray(classes)
+            n_compiled = rf_step._cache_size()
 
-        t = jnp.asarray([s.local_step * dt if s.active else 0.0
-                         for s in slots], jnp.float32)
-        n_compiled = rf_step._cache_size()
         t_tick = time.perf_counter()
-        span = (tracer.span("tick", cat="step",
-                            args={"tick": tick, "slotted": bool(slotted)})
-                if tracer is not None else contextlib.nullcontext())
-        with span:
-            x, states, states_u, _, _, aux = rf_step(
-                x, jnp.asarray(classes), states, states_u, {}, {}, t,
-                jax.random.fold_in(step_key, tick), plan=plan,
-                slotted=slotted, slot_fresh=slot_fresh, consume_mask=consume)
+        with _span(tracer, "tick", {"tick": tick, "slotted": bool(slotted)},
+                   cat="step"):
+            # the host's launch of the step: argument flattening and
+            # enqueue (a compile, on a variant's first tick)
+            with _span(tracer, "serve.dispatch"):
+                x, states, states_u, _, _, aux = rf_step(
+                    x, cls, states, states_u, {}, {}, t, tick_key,
+                    plan=plan, slotted=slotted, slot_fresh=slot_fresh,
+                    consume_mask=consume)
             if (fplan is not None and plan_lib.overlap_of(dcfg)
                     and fplan.hop_delay(tick)):
                 # injected slow ring hop (Sec. 17): host-visible, so the
@@ -1069,67 +1106,75 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
             # measured (not modeled) per-tick walltime.  The tick already
             # waits for the step when it reads aux back below, so this
             # sync costs no overlap.
-            jax.block_until_ready(x)
+            with _span(tracer, "serve.wait"):
+                jax.block_until_ready(x)
         wall = time.perf_counter() - t_tick
-        if rf_step._cache_size() > n_compiled:
-            # first tick of a (plan, slotted) variant: trace + compile +
-            # one execution, kept out of the steady per-tick times
-            compile_s["slotted" if slotted else
-                      f"v{splan.variant_of_step[plan_idx]}"] = wall
-        else:
-            tick_s.append(wall)
-        if obs_on:
-            reg.histogram("dice_step_wall_seconds",
-                          "measured wall seconds per engine tick",
-                          lab).observe(wall)
-            if "telemetry" in aux:
-                _publish_telemetry_step(reg, aux["telemetry"], lab)
-        if ctrl is not None:
-            codec_err = None
-            if "telemetry" in aux:
-                codec_err = float(np.asarray(
-                    aux["telemetry"])[:, obs_fields.CODEC_ERR].mean())
-            if ctrl.observe_step(wall, codec_err):
-                reg.counter("dice_watchdog_breaches_total",
-                            "engine-tick step-deadline breaches",
-                            lab).inc()
 
-        n_free = sum(not s.active for s in slots)
-        reg.counter("dice_ticks_total", "engine ticks executed", lab).inc()
-        if slotted:
-            reg.counter("dice_slotted_ticks_total",
-                        "ticks on the slotted merge plan", lab).inc()
-        reg.counter("dice_padded_slot_steps_total",
-                    "free-slot step executions", lab).inc(n_free)
-        reg.series("dice_slot_occupancy", "active-slot fraction per tick",
-                   lab).append(1.0 - n_free / B)
-        reg.series("dice_queue_depth", "requests still waiting",
-                   lab).append(len(queue))
-        if "fault_events" in aux:
-            fe = np.asarray(aux["fault_events"])
-            for idx, nm in enumerate(("corrupt_combine", "guarded_combine",
-                                      "corrupt_dispatch",
-                                      "guarded_dispatch")):
-                if fe[idx]:
-                    reg.counter("dice_fault_events_total",
-                                "in-graph wire corruption / guard events",
-                                {**lab, "event": nm}).inc(float(fe[idx]))
-        hist.update(np.asarray(aux["expert_counts"]))
-        reg.counter("dice_dispatch_bytes_total", "dispatch payload moved",
-                    lab).inc(float(aux["dispatch_bytes"]))
-        reg.counter("dice_wire_bytes_total",
-                    "codec-compressed bytes on the wire",
-                    lab).inc(float(aux["dispatch_bytes"]))
-        reg.counter("dice_raw_bytes_total",
-                    "lossless-equivalent payload bytes",
-                    lab).inc(float(aux["raw_dispatch_bytes"]))
-        reg.counter("dice_hop_bytes_total", "per-device one-hop ring wire",
-                    lab).inc(float(aux["hop_bytes"]))
-        reg.gauge("dice_ring_hops", "ring collective-permutes per MoE layer",
-                  lab).set_max(int(aux["hops"]))
-        reg.gauge("dice_buffer_bytes",
-                  "persistent staleness-buffer footprint",
-                  lab).set(int(aux["buffer_bytes"]))
+        # ---- aux read-back and the registry updates that consume it ------
+        rb = {"tick": tick, "reads": 0}
+        with _span(tracer, "serve.readback", rb):
+            if rf_step._cache_size() > n_compiled:
+                # first tick of a (plan, slotted) variant: trace + compile
+                # + one execution, kept out of the steady per-tick times
+                compile_s["slotted" if slotted else
+                          f"v{splan.variant_of_step[plan_idx]}"] = wall
+            else:
+                tick_s.append(wall)
+            tel = None
+            if "telemetry" in aux and (obs_on or ctrl is not None):
+                tel = _read(aux["telemetry"], rb)
+            if obs_on:
+                reg.histogram("dice_step_wall_seconds",
+                              "measured wall seconds per engine tick",
+                              lab).observe(wall)
+                if tel is not None:
+                    _publish_telemetry_step(reg, tel, lab)
+            if ctrl is not None:
+                codec_err = (None if tel is None
+                             else float(tel[:, obs_fields.CODEC_ERR].mean()))
+                if ctrl.observe_step(wall, codec_err):
+                    reg.counter("dice_watchdog_breaches_total",
+                                "engine-tick step-deadline breaches",
+                                lab).inc()
+
+            n_free = sum(not s.active for s in slots)
+            reg.counter("dice_ticks_total", "engine ticks executed",
+                        lab).inc()
+            if slotted:
+                reg.counter("dice_slotted_ticks_total",
+                            "ticks on the slotted merge plan", lab).inc()
+            reg.counter("dice_padded_slot_steps_total",
+                        "free-slot step executions", lab).inc(n_free)
+            reg.series("dice_slot_occupancy", "active-slot fraction per tick",
+                       lab).append(1.0 - n_free / B)
+            reg.series("dice_queue_depth", "requests still waiting",
+                       lab).append(len(queue))
+            if "fault_events" in aux:
+                fe = _read(aux["fault_events"], rb)
+                for idx, nm in enumerate(("corrupt_combine",
+                                          "guarded_combine",
+                                          "corrupt_dispatch",
+                                          "guarded_dispatch")):
+                    if fe[idx]:
+                        reg.counter("dice_fault_events_total",
+                                    "in-graph wire corruption / guard events",
+                                    {**lab, "event": nm}).inc(float(fe[idx]))
+            hist.update(_read(aux["expert_counts"], rb))
+            reg.counter("dice_dispatch_bytes_total",
+                        "dispatch payload moved",
+                        lab).inc(float(_read(aux["dispatch_bytes"], rb)))
+            reg.counter("dice_raw_bytes_total",
+                        "lossless-equivalent payload bytes",
+                        lab).inc(float(_read(aux["raw_dispatch_bytes"], rb)))
+            reg.counter("dice_hop_bytes_total",
+                        "per-device one-hop ring wire",
+                        lab).inc(float(_read(aux["hop_bytes"], rb)))
+            reg.gauge("dice_ring_hops",
+                      "ring collective-permutes per MoE layer",
+                      lab).set_max(int(_read(aux["hops"], rb)))
+            reg.gauge("dice_buffer_bytes",
+                      "persistent staleness-buffer footprint",
+                      lab).set(int(_read(aux["buffer_bytes"], rb)))
 
         # ---- slot quarantine (Sec. 17 rung 4) ----------------------------
         # a non-finite lane — corruption that escaped the wire guards, or
@@ -1139,68 +1184,84 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
         # requeued for a deterministic replay (request_noise is rid-keyed)
         # up to max_requeues, then shed.
         if res is not None and res.quarantine:
-            if fplan is not None and fplan.poison(tick):
-                victim = next((i for i, s in enumerate(slots) if s.active),
-                              None)
-                if victim is not None:
-                    x = x.at[victim].set(jnp.nan)
-                    if tracer is not None:
-                        tracer.instant("poison", args={"slot": victim,
-                                                       "tick": tick})
-            bad = ~np.isfinite(np.asarray(x).reshape(B, -1)).all(axis=1)
-            hit = [i for i in range(B) if bad[i] and slots[i].active]
-            if hit:
-                qm = np.zeros(B, bool)
-                for i in hit:
+            qa = {"tick": tick, "quarantined": 0}
+            with _span(tracer, "serve.quarantine", qa):
+                if fplan is not None and fplan.poison(tick):
+                    victim = next((i for i, s in enumerate(slots)
+                                   if s.active), None)
+                    if victim is not None:
+                        x = x.at[victim].set(jnp.nan)
+                        if tracer is not None:
+                            tracer.instant("poison", args={"slot": victim,
+                                                           "tick": tick})
+                bad = ~np.isfinite(np.asarray(x).reshape(B, -1)).all(axis=1)
+                hit = [i for i in range(B) if bad[i] and slots[i].active]
+                qa["quarantined"] = len(hit)
+                if hit:
+                    qm = np.zeros(B, bool)
+                    for i in hit:
+                        slot = slots[i]
+                        reg.counter("dice_quarantined_slots_total",
+                                    "poisoned slots quarantined", lab).inc()
+                        if tracer is not None:
+                            tracer.instant("quarantine", args={
+                                "rid": slot.rid, "slot": i, "tick": tick})
+                        if queue.requeue(tick,
+                                         Request(class_id=slot.class_id,
+                                                 rid=slot.rid),
+                                         res.max_requeues):
+                            reg.counter("dice_requeued_requests_total",
+                                        "quarantined requests requeued",
+                                        lab).inc()
+                        else:
+                            reg.counter("dice_shed_requests_total",
+                                        "requests shed by admission bounds",
+                                        lab).inc()
+                        admit_time.pop(slot.rid, None)
+                        qm[i] = True
+                        slots[i] = _Slot()
+                        classes[i] = cfg.num_classes
+                    m = jnp.asarray(qm)
+                    x = jnp.where(m[:, None, None], 0.0, x)
+                    states = stale_lib.reset_slots(states, m,
+                                                   tokens_per_slot=Tp)
+                    states_u = stale_lib.reset_slots(states_u, m,
+                                                     tokens_per_slot=Tp)
+                    if mesh is not None:
+                        states = stale_lib.shard_states(states, mesh,
+                                                        ep_axis=b_dim)
+                        states_u = stale_lib.shard_states(states_u, mesh,
+                                                          ep_axis=b_dim)
+                        x = _place(x)
+
+        # ---- completion: finished lanes' samples to the host --------------
+        finished = []
+        for i, slot in enumerate(slots):
+            if slot.active:
+                slot.local_step += 1
+                if slot.local_step >= num_steps:
+                    finished.append(i)
+        if finished:
+            with _span(tracer, "serve.complete",
+                       {"tick": tick, "finished": len(finished)}):
+                for i in finished:
                     slot = slots[i]
-                    reg.counter("dice_quarantined_slots_total",
-                                "poisoned slots quarantined", lab).inc()
+                    out[slot.rid] = np.asarray(x[i])
+                    reg.counter("dice_requests_total", "requests served",
+                                lab).inc()
+                    if slot.rid in admit_time:
+                        reg.histogram(
+                            "dice_request_service_seconds",
+                            "request service seconds (admission->sample; "
+                            "the queue wait is not in it)",
+                            lab).observe(
+                                time.perf_counter()
+                                - admit_time.pop(slot.rid))
                     if tracer is not None:
-                        tracer.instant("quarantine", args={
+                        tracer.instant("done", args={
                             "rid": slot.rid, "slot": i, "tick": tick})
-                    if queue.requeue(tick, Request(class_id=slot.class_id,
-                                                   rid=slot.rid),
-                                     res.max_requeues):
-                        reg.counter("dice_requeued_requests_total",
-                                    "quarantined requests requeued",
-                                    lab).inc()
-                    else:
-                        reg.counter("dice_shed_requests_total",
-                                    "requests shed by admission bounds",
-                                    lab).inc()
-                    admit_time.pop(slot.rid, None)
-                    qm[i] = True
                     slots[i] = _Slot()
                     classes[i] = cfg.num_classes
-                m = jnp.asarray(qm)
-                x = jnp.where(m[:, None, None], 0.0, x)
-                states = stale_lib.reset_slots(states, m,
-                                               tokens_per_slot=Tp)
-                states_u = stale_lib.reset_slots(states_u, m,
-                                                 tokens_per_slot=Tp)
-                if mesh is not None:
-                    states = stale_lib.shard_states(states, mesh,
-                                                    ep_axis=b_dim)
-                    states_u = stale_lib.shard_states(states_u, mesh,
-                                                      ep_axis=b_dim)
-                    x = _place(x)
-
-        for i, slot in enumerate(slots):
-            if not slot.active:
-                continue
-            slot.local_step += 1
-            if slot.local_step >= num_steps:
-                out[slot.rid] = np.asarray(x[i])
-                reg.counter("dice_requests_total", "requests served",
-                            lab).inc()
-                if slot.rid in admit_time:
-                    reg.histogram(
-                        "dice_request_e2e_seconds",
-                        "request end-to-end seconds (admission->sample)",
-                        lab).observe(
-                            time.perf_counter() - admit_time.pop(slot.rid))
-                slots[i] = _Slot()
-                classes[i] = cfg.num_classes
         tick += 1
 
     # the latency model describes the REQUESTED deployment (server.dcfg,
@@ -1252,7 +1313,7 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
         "buffer_bytes": int(reg.value("dice_buffer_bytes", lab)),
         "dispatch_bytes_total": reg.value("dice_dispatch_bytes_total", lab),
         # wire vs raw payload flows (Sec. 11): wire == dispatch_bytes_total
-        "wire_bytes_total": reg.value("dice_wire_bytes_total", lab),
+        "wire_bytes_total": reg.value("dice_dispatch_bytes_total", lab),
         "raw_bytes_total": reg.value("dice_raw_bytes_total", lab),
         "num_plan_variants": splan.num_variants,
         "jit_cache_size": int(reg.value("dice_jit_cache_size", lab)),

@@ -30,7 +30,6 @@ from repro.core.patch_parallel import (PatchParallelState,
 from repro.core.schedules import DiceConfig, Schedule
 from repro.core import moe as moe_lib
 from repro.models import layers as L
-from repro.obs import telemetry as obs_telemetry
 from repro.obs.telemetry import ObsConfig
 from repro.resilience import faults as fault_lib
 
@@ -152,10 +151,11 @@ def dit_forward(params, x, t, y, cfg: ModelConfig, dcfg: DiceConfig,
     ``obs`` (DESIGN.md Sec. 16): an enabled :class:`ObsConfig` adds the
     fixed-shape ``"telemetry"`` (L, NUM_FIELDS) block to the aux dict
     (per-layer staleness age / residual energies / mask rate / drop
-    fraction / codec error) and names each MoE layer action with
-    ``jax.named_scope``.  It is a closure constant, never a traced or
-    static *argument*, and with ``obs=None`` (default) the traced graph
-    is byte-identical to a build without the subsystem.
+    fraction / codec error).  It is a closure constant, never a traced
+    or static *argument*, and with ``obs=None`` (default) the traced
+    graph is byte-identical to a build without the subsystem.  Every op
+    carries its layer part's ``jax.named_scope`` (``layer_NN/attn``,
+    ``layer_NN/router``, ...) either way: metadata only.
     Returns (v, new_states, new_patch_states, aux dict).
     """
     if plan is None:
@@ -206,65 +206,67 @@ def dit_forward(params, x, t, y, cfg: ModelConfig, dcfg: DiceConfig,
         if res is not None else None
 
     for i, blk in enumerate(params["blocks"]):
-        if paged and plan.actions[i].paging is not None:
-            # issue this layer's fetch (a no-op past layer 0: the previous
-            # layer already prefetched it) and the depth-ahead prefetch —
-            # BEFORE this layer's compute, so the transfer rides behind
-            # the attention + ring hops about to be traced
-            _ensure_fetched(i)
-            if plan.actions[i].prefetch is not None:
-                _ensure_fetched(plan.actions[i].prefetch)
-        mod = jax.nn.silu(c) @ blk["adaln"]         # (B, 6d)
-        s1, sc1, g1, s2, sc2, g2 = jnp.split(mod, 6, axis=-1)
+        # every op of the layer under layer_NN: attn here, the MoE
+        # parts (router, dispatch, expert_ffn, combine, shared_ffn,
+        # stale_select) inside core/moe.py and core/staleness.py
+        with jax.named_scope(f"layer_{i:02d}"):
+            if paged and plan.actions[i].paging is not None:
+                # issue this layer's fetch (a no-op past layer 0: the previous
+                # layer already prefetched it) and the depth-ahead prefetch —
+                # BEFORE this layer's compute, so the transfer rides behind
+                # the attention + ring hops about to be traced
+                _ensure_fetched(i)
+                if plan.actions[i].prefetch is not None:
+                    _ensure_fetched(plan.actions[i].prefetch)
+            mod = jax.nn.silu(c) @ blk["adaln"]         # (B, 6d)
+            s1, sc1, g1, s2, sc2, g2 = jnp.split(mod, 6, axis=-1)
 
-        hn = _modulate(L.rmsnorm(blk["ln1"], h, eps=cfg.norm_eps), s1, sc1)
-        if patch_parallel_ndev or patch_axis is not None:
-            q = (hn @ blk["attn"]["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
-            k = (hn @ blk["attn"]["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-            v = (hn @ blk["attn"]["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-            pstate = patch_states.get(i, PatchParallelState()) if patch_states else PatchParallelState()
-            if patch_axis is not None:
-                attn, pnew = sharded_patch_attention(
-                    q, k, v, pstate, patch_axis=patch_axis,
-                    fresh=patch_fresh)
-            else:
-                attn, pnew = displaced_patch_attention(
-                    q, k, v, pstate, n_dev=patch_parallel_ndev,
-                    warmup=plan.is_warmup)
-            attn = attn.reshape(B, T, -1) @ blk["attn"]["wo"]
-            new_patch[i] = pnew
-        else:
-            attn, _ = L.attn_apply(blk["attn"], hn, positions, cfg,
-                                   causal=False)
-        h = h + g1[:, None, :] * attn
+            with jax.named_scope("attn"):
+                hn = _modulate(L.rmsnorm(blk["ln1"], h, eps=cfg.norm_eps), s1, sc1)
+                if patch_parallel_ndev or patch_axis is not None:
+                    q = (hn @ blk["attn"]["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
+                    k = (hn @ blk["attn"]["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+                    v = (hn @ blk["attn"]["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+                    pstate = patch_states.get(i, PatchParallelState()) if patch_states else PatchParallelState()
+                    if patch_axis is not None:
+                        attn, pnew = sharded_patch_attention(
+                            q, k, v, pstate, patch_axis=patch_axis,
+                            fresh=patch_fresh)
+                    else:
+                        attn, pnew = displaced_patch_attention(
+                            q, k, v, pstate, n_dev=patch_parallel_ndev,
+                            warmup=plan.is_warmup)
+                    attn = attn.reshape(B, T, -1) @ blk["attn"]["wo"]
+                    new_patch[i] = pnew
+                else:
+                    attn, _ = L.attn_apply(blk["attn"], hn, positions, cfg,
+                                           causal=False)
+                h = h + g1[:, None, :] * attn
 
-        hn = _modulate(L.rmsnorm(blk["ln2"], h, eps=cfg.norm_eps), s2, sc2)
-        if patch_parallel_ndev and not patch_compose:
-            # DistriFusion replicates the model: MoE runs locally + fresh.
-            flat = hn.reshape(B * T, d)
-            with obs_telemetry.scope(obs, f"moe_l{i:02d}_distrifusion"):
+            hn = _modulate(L.rmsnorm(blk["ln2"], h, eps=cfg.norm_eps), s2, sc2)
+            if patch_parallel_ndev and not patch_compose:
+                # DistriFusion replicates the model: MoE runs locally + fresh.
+                flat = hn.reshape(B * T, d)
                 moe_out, aux = moe_lib.moe_forward(blk["moe"], flat, cfg,
                                                    use_pallas=use_pallas,
                                                    obs=obs, resilience=res,
                                                    fault_salt=i)
-            new_st = stale_lib.MoELayerState()
-        else:
-            flat = hn.reshape(B * T, d)
-            st = states[i]
-            if patch_axis is not None:
-                # factored (B, T, ...) buffers (the only layout that
-                # shards over patch) -> local flat rows, batch-major like
-                # ``flat`` above
-                st = stale_lib.flatten_state(st)
-            moe_p = blk["moe"]
-            wire_E = None
-            if paged and plan.actions[i].paging is not None:
-                shards = fetched.pop(i)
-                moe_p = dict(moe_p, **shards)
-                wire_E = (shards["experts_gate"].shape[0]
-                          * compat.axis_size(ep_axis))
-            with obs_telemetry.scope(
-                    obs, f"moe_l{i:02d}_{plan.actions[i].mode}"):
+                new_st = stale_lib.MoELayerState()
+            else:
+                flat = hn.reshape(B * T, d)
+                st = states[i]
+                if patch_axis is not None:
+                    # factored (B, T, ...) buffers (the only layout that
+                    # shards over patch) -> local flat rows, batch-major like
+                    # ``flat`` above
+                    st = stale_lib.flatten_state(st)
+                moe_p = blk["moe"]
+                wire_E = None
+                if paged and plan.actions[i].paging is not None:
+                    shards = fetched.pop(i)
+                    moe_p = dict(moe_p, **shards)
+                    wire_E = (shards["experts_gate"].shape[0]
+                              * compat.axis_size(ep_axis))
                 moe_out, new_st, aux = stale_lib.apply_layer_action(
                     moe_p, flat, cfg, plan.actions[i], st,
                     key=key, ep_axis=ep_axis, use_pallas=use_pallas,
@@ -272,21 +274,21 @@ def dit_forward(params, x, t, y, cfg: ModelConfig, dcfg: DiceConfig,
                     reduce_axes=reduce_axes, hop_schedule=hop_schedule,
                     num_wire_experts=wire_E, obs=obs,
                     resilience=res, layer_idx=i)
-            if patch_axis is not None:
-                new_st = stale_lib.unflatten_state(new_st, B, T)
-        new_states[i] = new_st
-        total_lb += aux.lb_loss
-        total_dispatch_bytes += aux.dispatch_bytes
-        total_raw_bytes += aux.raw_dispatch_bytes
-        if aux.hops is not None:
-            ring_hops = jnp.maximum(ring_hops, aux.hops)
-            total_hop_bytes += aux.hop_bytes
-        dropped += aux.dropped_frac
-        served_counts.append(aux.served_counts)
-        telems.append(aux.telemetry)
-        if fault_events is not None and aux.fault_events is not None:
-            fault_events = fault_events + aux.fault_events
-        h = h + g2[:, None, :] * moe_out.reshape(B, T, d).astype(h.dtype)
+                if patch_axis is not None:
+                    new_st = stale_lib.unflatten_state(new_st, B, T)
+            new_states[i] = new_st
+            total_lb += aux.lb_loss
+            total_dispatch_bytes += aux.dispatch_bytes
+            total_raw_bytes += aux.raw_dispatch_bytes
+            if aux.hops is not None:
+                ring_hops = jnp.maximum(ring_hops, aux.hops)
+                total_hop_bytes += aux.hop_bytes
+            dropped += aux.dropped_frac
+            served_counts.append(aux.served_counts)
+            telems.append(aux.telemetry)
+            if fault_events is not None and aux.fault_events is not None:
+                fault_events = fault_events + aux.fault_events
+            h = h + g2[:, None, :] * moe_out.reshape(B, T, d).astype(h.dtype)
 
     fmod = jax.nn.silu(c) @ params["final_mod"]
     fs, fsc = jnp.split(fmod, 2, axis=-1)

@@ -44,11 +44,9 @@ the reported block is the shard-mean and replicated.
 """
 from __future__ import annotations
 
-import contextlib
 from dataclasses import dataclass
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 TELEMETRY_FIELDS = (
@@ -72,11 +70,10 @@ class ObsConfig:
     or jit-static *argument*, so it cannot multiply jit-cache entries).
 
     ``enabled=False`` (the default everywhere) keeps every traced graph
-    byte-identical to a build without the subsystem.  ``annotate``
-    additionally wraps each MoE layer action in a ``jax.named_scope`` so
-    device profiles line up with the plan's per-layer modes."""
+    byte-identical to a build without the subsystem.  The device-side
+    names of the layer parts (``jax.named_scope``) do not depend on it:
+    the model carries them always."""
     enabled: bool = False
-    annotate: bool = True
 
 
 def _rel_energy(diff, ref):
@@ -140,15 +137,6 @@ def stamp_age(aux, action, obs: Optional[ObsConfig]):
         return aux
     return aux._replace(telemetry=aux.telemetry.at[AGE].set(
         jnp.float32(action.staleness)))
-
-
-def scope(obs: Optional[ObsConfig], name: str):
-    """A ``jax.named_scope`` when annotation is on, else a no-op context.
-    The names land in lowered HLO metadata, so device profiles captured
-    with ``jax.profiler`` line up with the plan's per-layer modes."""
-    if obs is not None and obs.enabled and obs.annotate:
-        return jax.named_scope(name)
-    return contextlib.nullcontext()
 
 
 def merge_staggered(t0, t1):

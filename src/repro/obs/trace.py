@@ -6,11 +6,17 @@ are complete spans (``ph == "X"`` with microsecond ``ts``/``dur``),
 instants (``ph == "i"``), and counter samples (``ph == "C"``).
 
 Host phases traced by the serving stack: plan build, per-variant jit
-compile, admission/eviction, paging ``io_callback`` fetches (emitted
-from the ExpertPool's fetch thread — the tracer is lock-protected), and
-step execution.  Device-side alignment comes from
-``jax.profiler.TraceAnnotation``/``jax.named_scope`` names the sampler
-adds around the same phases when observability is on.
+compile, the engine's per-tick phases (``serve.*`` spans, see
+``repro.launch.serve.serve_continuous``), admission and completion
+instants, paging ``io_callback`` fetches (emitted from the ExpertPool's
+fetch thread — the tracer is lock-protected), and step execution.
+
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name:
+while ``jax.profiler`` records, it lands in the capture as a host event
+on the device trace's clock, with no alignment step; with no profiler
+running it costs about a microsecond.  The device side carries the
+model's ``jax.named_scope`` names (``layer_NN/attn``, ``router``, ...)
+in its ops' metadata, whatever the observability setting.
 """
 from __future__ import annotations
 
@@ -20,19 +26,23 @@ import time
 from contextlib import contextmanager
 from typing import Dict, Optional
 
+import jax
+
 
 class StepTracer:
     """Thread-safe collector of Chrome trace events.
 
     All timestamps are microseconds relative to tracer construction,
-    taken from ``time.perf_counter()``.  ``tid`` is the emitting thread,
-    so paging fetches land on their own track.
+    taken from ``time.perf_counter()``; ``origin_unix_ns`` is that origin
+    on the wall clock.  ``tid`` is the emitting thread, so paging fetches
+    land on their own track.
     """
 
     def __init__(self, pid: int = 1):
         self.pid = pid
         self._lock = threading.Lock()
         self._t0 = time.perf_counter()
+        self.origin_unix_ns = time.time_ns()
         self.events = []
 
     # -- time ------------------------------------------------------------
@@ -53,10 +63,13 @@ class StepTracer:
     @contextmanager
     def span(self, name: str, cat: str = "host",
              args: Optional[Dict] = None):
-        """Complete-event span around a ``with`` block."""
+        """Complete-event span around a ``with`` block, entered as a
+        ``jax.profiler.TraceAnnotation`` of the same name.  ``args`` is
+        read as the block ends, so the block may fill it in."""
         t0 = self.now()
         try:
-            yield self
+            with jax.profiler.TraceAnnotation(name):
+                yield self
         finally:
             ev = self._base(name, cat)
             ev.update(ph="X", ts=t0, dur=self.now() - t0,
@@ -87,7 +100,8 @@ class StepTracer:
     def to_json(self) -> dict:
         with self._lock:
             events = list(self.events)
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
+        return {"traceEvents": events, "displayTimeUnit": "ms",
+                "otherData": {"origin_unix_ns": self.origin_unix_ns}}
 
     def write(self, path) -> None:
         with open(path, "w") as f:

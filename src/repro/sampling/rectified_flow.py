@@ -14,6 +14,7 @@ light steps still get a genuinely smaller dispatch buffer.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from functools import partial
 from typing import Dict, Optional
@@ -551,40 +552,33 @@ def rf_sample(params, cfg: ModelConfig, dcfg: DiceConfig, *,
         v_idx = splan.variant_of_step[s]
         t0 = time.perf_counter() if obs_on else 0.0
         cache_before = one_step._cache_size() if obs_on else 0
-        if obs_on:
-            # device-profile alignment: the annotation names this step's
-            # plan variant on the host timeline jax.profiler captures
-            with jax.profiler.TraceAnnotation(f"rf_step_v{v_idx}"):
-                step_out = one_step(
-                    x, states, states_u, patch_states, patch_states_u,
-                    t, k, plan=splan.steps[s], patch_fresh=pf)
-        else:
+        # the step's span (a profiler annotation too) names its plan
+        # variant; "compiled" is filled in once the call has returned
+        step_args = {"step": s, "variant": v_idx}
+        with (tracer.span("rf_step", cat="step", args=step_args)
+              if obs_on and tracer is not None else contextlib.nullcontext()):
             step_out = one_step(
                 x, states, states_u, patch_states, patch_states_u, t, k,
                 plan=splan.steps[s], patch_fresh=pf)
-        x, states, states_u, patch_states, patch_states_u, aux = step_out
+            x, states, states_u, patch_states, patch_states_u, aux = step_out
+            if obs_on:
+                t_dispatched = time.perf_counter()
+                compiled = one_step._cache_size() > cache_before
+                step_args["compiled"] = bool(compiled)
+                if compiled and v_idx not in stats["compile_s"]:
+                    # jit compiles synchronously inside the call, so the
+                    # call-to-return time of a cache-growing step IS the
+                    # trace+compile cost of its variant
+                    stats["compile_s"][v_idx] = t_dispatched - t0
+                    if tracer is not None:
+                        tracer.complete(
+                            f"compile_variant_{v_idx}",
+                            tracer.now() - (t_dispatched - t0) * 1e6,
+                            cat="compile", args={"variant": v_idx, "step": s})
+                jax.block_until_ready(x)
         if obs_on:
-            t_dispatched = time.perf_counter()
-            compiled = one_step._cache_size() > cache_before
-            if compiled and v_idx not in stats["compile_s"]:
-                # jit compiles synchronously inside the call, so the
-                # call-to-return time of a cache-growing step IS the
-                # trace+compile cost of its variant
-                stats["compile_s"][v_idx] = t_dispatched - t0
-                if tracer is not None:
-                    tracer.complete(f"compile_variant_{v_idx}",
-                                    tracer.now() - (t_dispatched - t0) * 1e6,
-                                    cat="compile",
-                                    args={"variant": v_idx, "step": s})
-            jax.block_until_ready(x)
-            wall = time.perf_counter() - t0
-            stats["step_wall_s"].append(wall)
+            stats["step_wall_s"].append(time.perf_counter() - t0)
             stats["telemetry"].append(jax.device_get(aux["telemetry"]))
-            if tracer is not None:
-                tracer.complete("rf_step", tracer.now() - wall * 1e6,
-                                cat="step",
-                                args={"step": s, "variant": v_idx,
-                                      "compiled": bool(compiled)})
         if collect_stats:
             stats["dispatch_bytes"].append(float(aux["dispatch_bytes"]))
             stats["raw_bytes"].append(float(aux["raw_dispatch_bytes"]))

@@ -1,0 +1,244 @@
+"""The continuous engine's own spans (``serve.*``), their place on the
+profiler's clock, and the always-on device scopes of the model.
+
+A ``StepTracer`` on ``server.tracer`` must see, per tick: ``serve.admit``
+(a tick that admits), ``serve.prepare``, the ``tick`` span holding
+``serve.dispatch`` and ``serve.wait``, ``serve.readback`` and, where a
+request finishes, ``serve.complete`` with one ``done`` instant per
+request.  The benchmark reads the ``tick`` span and ``admit`` instant by
+name and args, so those are pinned here too.  Every span is also a
+``jax.profiler.TraceAnnotation``: a profiler capture holds the names as
+host events.  The model's layer parts carry ``jax.named_scope`` names
+in their HLO metadata whatever the observability setting.
+"""
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.configs.dit_moe_xl import tiny
+from repro.core import plan as plan_lib
+from repro.core import staleness as stale_lib
+from repro.core.schedules import DiceConfig
+from repro.launch.serve import DiceServer, Request, serve_continuous
+from repro.obs import StepTracer
+from repro.resilience.faults import ResilienceConfig
+from repro.sampling.rectified_flow import make_rf_step
+
+NUM_STEPS = 4
+B = 4
+# two cohorts: the second waits for the first lanes to free up
+ARRIVALS = [0.0] * B + [1.0] * B
+BETWEEN_TICKS = ("serve.admit", "serve.prepare", "serve.readback",
+                 "serve.complete")
+IN_TICK = ("serve.dispatch", "serve.wait")
+
+
+def small_cfg():
+    return tiny().replace(name="spans-test", num_layers=2, d_model=32,
+                          d_ff=64, num_heads=2, num_kv_heads=2, head_dim=16,
+                          moe_d_ff=32, patch_tokens=8, capacity_factor=2.0)
+
+
+def _serve(tracer=None, resilience=None, n=len(ARRIVALS)):
+    cfg = small_cfg()
+    server = DiceServer(cfg, DiceConfig.dice(), seed=0,
+                        resilience=resilience)
+    server.tracer = tracer
+    reqs = [Request(class_id=i % cfg.num_classes, rid=i) for i in range(n)]
+    return serve_continuous(server, reqs, max_batch=B, num_steps=NUM_STEPS,
+                            arrival_steps=ARRIVALS[:n]), server
+
+
+@pytest.fixture(scope="module")
+def traced():
+    tracer = StepTracer()
+    (out, stats), server = _serve(tracer)
+    return dict(out=out, stats=stats, events=list(tracer.events),
+                server=server)
+
+
+def _named(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def _inside(e, outer):
+    return (outer["ts"] <= e["ts"]
+            and e["ts"] + e["dur"] <= outer["ts"] + outer["dur"])
+
+
+def _overlaps(e, outer):
+    return (e["ts"] < outer["ts"] + outer["dur"]
+            and outer["ts"] < e["ts"] + e["dur"])
+
+
+def test_every_engine_span_is_emitted(traced):
+    names = {e["name"] for e in traced["events"]}
+    assert set(BETWEEN_TICKS + IN_TICK) <= names
+    ticks = _named(traced["events"], "tick")
+    assert len(ticks) == traced["stats"]["ticks"]
+    # one prepare and one readback per tick; admit on the two admitting
+    # ticks only, complete on the two ticks where a cohort finishes
+    assert len(_named(traced["events"], "serve.prepare")) == len(ticks)
+    assert len(_named(traced["events"], "serve.readback")) == len(ticks)
+    admits = _named(traced["events"], "serve.admit")
+    assert [e["args"] for e in admits] == [
+        {"tick": 0, "admitted": B}, {"tick": NUM_STEPS, "admitted": B}]
+    assert len(_named(traced["events"], "serve.complete")) == 2
+
+
+def test_dispatch_and_wait_nest_in_each_tick(traced):
+    ev = traced["events"]
+    for tk in _named(ev, "tick"):
+        for name in IN_TICK:
+            inner = [e for e in _named(ev, name) if _overlaps(e, tk)]
+            assert len(inner) == 1, (name, tk["args"])
+            assert _inside(inner[0], tk), (name, tk["args"])
+        d, w = (next(e for e in _named(ev, n) if _overlaps(e, tk))
+                for n in IN_TICK)
+        assert d["ts"] + d["dur"] <= w["ts"]      # launch, then the wait
+
+
+def test_between_tick_spans_lie_outside_every_tick(traced):
+    ev = traced["events"]
+    ticks = _named(ev, "tick")
+    for name in BETWEEN_TICKS:
+        for e in _named(ev, name):
+            assert not any(_overlaps(e, tk) for tk in ticks), (name, e)
+
+
+def test_one_done_per_request_with_its_admit_rid(traced):
+    ev = traced["events"]
+    admits = {e["args"]["rid"]: e["args"] for e in _named(ev, "admit")}
+    dones = {e["args"]["rid"]: e["args"] for e in _named(ev, "done")}
+    assert len(_named(ev, "done")) == len(traced["out"]) == len(ARRIVALS)
+    assert set(dones) == set(admits) == set(traced["out"])
+    for rid, d in dones.items():
+        assert d["slot"] == admits[rid]["slot"]
+        assert d["tick"] == admits[rid]["tick"] + NUM_STEPS - 1
+        done_ev = next(e for e in _named(ev, "done")
+                       if e["args"]["rid"] == rid)
+        complete = [c for c in _named(ev, "serve.complete")
+                    if c["ts"] <= done_ev["ts"] <= c["ts"] + c["dur"]]
+        assert len(complete) == 1
+
+
+def test_tick_and_admit_args_as_the_benchmark_reads_them(traced):
+    ev = traced["events"]
+    for e in _named(ev, "tick"):
+        assert e["ph"] == "X" and e["cat"] == "step"
+        assert set(e["args"]) == {"tick", "slotted"}
+        assert isinstance(e["args"]["tick"], int)
+        assert isinstance(e["args"]["slotted"], bool)
+    assert [e["args"]["tick"] for e in _named(ev, "tick")] == list(
+        range(len(_named(ev, "tick"))))
+    for e in _named(ev, "admit"):
+        assert e["ph"] == "i"
+        assert set(e["args"]) == {"rid", "slot", "tick", "recycled"}
+        assert all(isinstance(e["args"][k], int)
+                   for k in ("rid", "slot", "tick"))
+
+
+def test_readback_counts_its_device_reads(traced):
+    # expert_counts, dispatch/raw/hop bytes, hops, buffer_bytes: six
+    # blocking reads a tick with observability and resilience off
+    for e in _named(traced["events"], "serve.readback"):
+        assert e["args"]["reads"] == 6
+
+
+def test_service_seconds_replace_e2e_on_the_continuous_engine(traced):
+    lab = {"schedule": "dice", "engine": "continuous"}
+    h = traced["server"].metrics.get("dice_request_service_seconds", lab)
+    assert h is not None and h.count == len(ARRIVALS)
+    assert traced["server"].metrics.get("dice_request_e2e_seconds",
+                                        lab) is None
+    assert traced["server"].metrics.get("dice_wire_bytes_total",
+                                        lab) is None
+    assert (traced["stats"]["wire_bytes_total"]
+            == traced["stats"]["dispatch_bytes_total"] > 0)
+
+
+def test_no_events_without_a_tracer(traced, monkeypatch):
+    emitted = []
+    monkeypatch.setattr(StepTracer, "_emit",
+                        lambda self, ev: emitted.append(ev))
+    (out, _), _ = _serve(tracer=None)
+    assert emitted == []
+    # the spans change nothing served
+    for rid, x in out.items():
+        assert np.asarray(x).tobytes() == np.asarray(
+            traced["out"][rid]).tobytes()
+
+
+def test_quarantine_scan_has_its_span():
+    tracer = StepTracer()
+    _serve(tracer, resilience=ResilienceConfig(guards=True), n=B)
+    q = _named(tracer.events, "serve.quarantine")
+    assert len(q) == len(_named(tracer.events, "tick"))
+    assert all(e["args"]["quarantined"] == 0 for e in q)
+
+
+def test_span_args_are_read_as_the_span_ends():
+    tr = StepTracer()
+    args = {"reads": 0}
+    with tr.span("serve.readback", args=args):
+        args["reads"] += 2
+    assert tr.events[-1]["args"] == {"reads": 2}
+    doc = tr.to_json()
+    assert doc["otherData"]["origin_unix_ns"] == tr.origin_unix_ns > 0
+
+
+def test_engine_spans_reach_a_profiler_capture(tmp_path):
+    tracer = StepTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve(tracer, n=B)
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    assert paths
+    pd = jax.profiler.ProfileData.from_file(paths[0])
+    host = {e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    want = {"tick", "serve.admit", "serve.prepare", "serve.dispatch",
+            "serve.wait", "serve.readback", "serve.complete", "plan_build"}
+    assert want <= host, want - host
+
+
+# ---------------------------------------------------------------------------
+# device scopes: always on, in the compiled step's op metadata
+# ---------------------------------------------------------------------------
+SCOPES = ("layer_00/attn", "layer_01/attn", "layer_00/router",
+          "layer_00/dispatch", "layer_00/expert_ffn", "layer_00/shared_ffn",
+          "layer_00/combine", "layer_00/stale_select")
+
+
+def test_compiled_step_carries_the_layer_part_scopes():
+    cfg = small_cfg()
+    server = DiceServer(cfg, DiceConfig.dice(), seed=0)
+    assert not server.obs.enabled
+    dcfg = server.dcfg
+    k = cfg.experts_per_token
+    merge = plan_lib.slotted_merge_plan(dcfg, cfg.num_layers,
+                                        experts_per_token=k)
+    splan = plan_lib.compile_step_plans(dcfg, cfg.num_layers, NUM_STEPS,
+                                        experts_per_token=k)
+    step = make_rf_step(server.params, cfg, dcfg, dt=1.0 / NUM_STEPS,
+                        obs=server.obs)
+    T = B * cfg.patch_tokens
+    states = stale_lib.init_planned_states(splan, num_tokens=T,
+                                           d_model=cfg.d_model, k=k,
+                                           dtype=jnp.float32)
+    x = jnp.zeros((B, cfg.patch_tokens, cfg.in_channels), jnp.float32)
+    hlo = step.lower(
+        x, jnp.zeros((B,), jnp.int32), states, states, {}, {},
+        jnp.zeros((B,), jnp.float32), jax.random.PRNGKey(0), plan=merge,
+        slotted=True, slot_fresh=jnp.ones((T,), bool),
+        consume_mask=jnp.ones((T, k), bool)).compile().as_text()
+    meta = [ln for ln in hlo.splitlines() if "op_name=" in ln]
+    for scope in SCOPES:
+        assert any(f"/{scope}/" in ln for ln in meta), scope
