@@ -720,8 +720,31 @@ def request_noise(key, rid: int, cfg: ModelConfig) -> jnp.ndarray:
     composition under ANY batching scheme and no bit-level equivalence
     across batch placements exists to preserve.
     """
-    return jax.random.normal(jax.random.fold_in(key, rid),
-                             (cfg.patch_tokens, cfg.in_channels))
+    return _lane_noise(key, rid, (cfg.patch_tokens, cfg.in_channels))
+
+
+def _lane_noise(key, rid, shape) -> jnp.ndarray:
+    return jax.random.normal(jax.random.fold_in(key, rid), shape)
+
+
+@partial(jax.jit, donate_argnums=(0, 1, 2))
+def _admit_lanes(x, states, states_u, recycle, rids, noise_key):
+    """A cohort's admission surgery as one program: every recycled lane
+    of ``x`` (B, T, C) takes its request's :func:`request_noise`, and its
+    rows of both staleness state sets are zeroed by
+    :func:`repro.core.staleness.reset_slots`.
+
+    ``recycle`` (B,) bool marks the admitted lanes; ``rids`` (B,) uint32
+    holds their request ids (``fold_in``'s own data type; the other
+    lanes' entries are ignored).  Both are traced, so one compile serves
+    every cohort, and ``x``, ``states`` and ``states_u`` are donated:
+    the caller holds only what this returns.
+    """
+    T, C = x.shape[1:]
+    noise = jax.vmap(lambda r: _lane_noise(noise_key, r, (T, C)))(rids)
+    x = jnp.where(recycle[:, None, None], noise, x)
+    return (x, stale_lib.reset_slots(states, recycle, tokens_per_slot=T),
+            stale_lib.reset_slots(states_u, recycle, tokens_per_slot=T))
 
 
 def serve_continuous(server: "DiceServer", requests: List[Request], *,
@@ -741,7 +764,10 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     :func:`repro.sampling.rectified_flow.make_rf_step`, its staleness rows
     zeroed by :func:`repro.core.staleness.reset_slots` — so no activation
     of the previous occupant leaks into the successor, and the jit cache
-    still holds exactly one entry per plan variant.
+    still holds exactly one entry per plan variant.  A tick's admissions
+    are one compiled program (:func:`_admit_lanes`: the lanes' noise and
+    the reset of both state sets) that donates the latents and both
+    state sets; those buffers never leave this function.
 
     Bit-identity of recycled-slot samples to fresh-batch samples holds
     for key-free sampling configurations (``router_jitter == 0`` and
@@ -762,13 +788,15 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
 
     With ``server.tracer`` set, every tick's host work is spanned, in
     order: ``serve.admit`` (a tick that admits; args ``tick``,
-    ``admitted``), ``serve.prepare`` (plan, slot masks, step inputs), the
-    ``tick`` span around ``serve.dispatch`` (the step's launch) and
-    ``serve.wait`` (``block_until_ready``), ``serve.readback`` (the aux
-    read-back and what consumes it; ``reads`` counts its blocking
-    device->host reads), ``serve.quarantine`` (resilience on) and
-    ``serve.complete`` (a tick where a request finishes).  A request's
-    ``admit`` and ``done`` instants share its ``rid``.
+    ``admitted``, ``reset_lanes``: the lanes the admission program
+    seeded and zeroed), ``serve.prepare`` (plan, slot masks, step
+    inputs), the ``tick`` span around ``serve.dispatch`` (the step's
+    launch) and ``serve.wait`` (``block_until_ready``),
+    ``serve.readback`` (the aux read-back and what consumes it; ``reads``
+    counts its blocking device->host reads), ``serve.quarantine``
+    (resilience on) and ``serve.complete`` (a tick where a request
+    finishes).  A request's ``admit`` and ``done`` instants share its
+    ``rid``.
     """
     cfg, dcfg = server.cfg, server.dcfg
     mesh = mesh if mesh is not None else server.mesh
@@ -986,12 +1014,13 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
         # ---- admission at plan-variant-aligned boundaries ----------------
         if tick % period == 0:
             recycle = np.zeros(B, bool)
+            rids = np.zeros(B, np.uint32)
             # the span marks a tick that admits: a lane is free and a
             # request has arrived (what pop_ready below tests)
             nxt = queue.next_arrival()
             admitting = (nxt is not None and nxt <= tick
                          and not all(s.active for s in slots))
-            adm = {"tick": tick, "admitted": 0}
+            adm = {"tick": tick, "admitted": 0, "reset_lanes": 0}
             with _span(tracer if admitting else None, "serve.admit", adm):
                 for i, slot in enumerate(slots):
                     if slot.active:
@@ -1002,8 +1031,8 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                     slots[i] = _Slot(rid=req.rid, class_id=req.class_id,
                                      local_step=0, active=True)
                     recycle[i] = True
+                    rids[i] = req.rid
                     classes[i] = req.class_id
-                    x = x.at[i].set(request_noise(noise_key, req.rid, cfg))
                     reg.counter("dice_admissions_total", "slot admissions",
                                 lab).inc()
                     if ever_used[i]:
@@ -1029,14 +1058,14 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                         tracer.instant("shed", args={"rid": rid,
                                                      "tick": tick})
                 if recycle.any():
-                    m = jnp.asarray(recycle)
-                    states = stale_lib.reset_slots(states, m,
-                                                   tokens_per_slot=Tp)
-                    states_u = stale_lib.reset_slots(states_u, m,
-                                                     tokens_per_slot=Tp)
+                    x, states, states_u = _admit_lanes(
+                        x, states, states_u, recycle, rids, noise_key)
+                    adm["reset_lanes"] = int(recycle.sum())
+                    reg.counter("dice_fused_admissions_total",
+                                "admission programs launched", lab).inc()
                     if mesh is not None:
-                        # re-place after host-side surgery: a drifted
-                        # layout would key extra jit-cache entries
+                        # re-place after the surgery: a drifted layout
+                        # would key extra jit-cache entries
                         states = stale_lib.shard_states(states, mesh,
                                                         ep_axis=b_dim)
                         states_u = stale_lib.shard_states(states_u, mesh,

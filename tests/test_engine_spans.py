@@ -23,7 +23,8 @@ from repro.configs.dit_moe_xl import tiny
 from repro.core import plan as plan_lib
 from repro.core import staleness as stale_lib
 from repro.core.schedules import DiceConfig
-from repro.launch.serve import DiceServer, Request, serve_continuous
+from repro.launch.serve import (DiceServer, Request, _admit_lanes,
+                                serve_continuous)
 from repro.obs import StepTracer
 from repro.resilience.faults import ResilienceConfig
 from repro.sampling.rectified_flow import make_rf_step
@@ -86,8 +87,31 @@ def test_every_engine_span_is_emitted(traced):
     assert len(_named(traced["events"], "serve.readback")) == len(ticks)
     admits = _named(traced["events"], "serve.admit")
     assert [e["args"] for e in admits] == [
-        {"tick": 0, "admitted": B}, {"tick": NUM_STEPS, "admitted": B}]
+        {"tick": 0, "admitted": B, "reset_lanes": B},
+        {"tick": NUM_STEPS, "admitted": B, "reset_lanes": B}]
     assert len(_named(traced["events"], "serve.complete")) == 2
+
+
+def test_admission_is_one_program_compiled_once():
+    """Each admitting tick launches the admission program once, with
+    every admitted lane seeded and zeroed in it; a second call on the
+    same server compiles nothing new."""
+    _admit_lanes.clear_cache()
+    tracer = StepTracer()
+    _, server = _serve(tracer)
+    cfg = server.cfg
+    reqs = [Request(class_id=(i + 1) % cfg.num_classes, rid=100 + i)
+            for i in range(len(ARRIVALS))]
+    serve_continuous(server, reqs, max_batch=B, num_steps=NUM_STEPS,
+                     arrival_steps=ARRIVALS, key=jax.random.PRNGKey(5))
+    assert _admit_lanes._cache_size() == 1
+    admits = _named(tracer.events, "serve.admit")
+    assert len(admits) == 4                     # two cohorts per call
+    assert all(e["args"]["reset_lanes"] == e["args"]["admitted"] == B
+               for e in admits)
+    lab = {"schedule": "dice", "engine": "continuous"}
+    assert server.metrics.value("dice_fused_admissions_total",
+                                lab) == len(admits)
 
 
 def test_dispatch_and_wait_nest_in_each_tick(traced):

@@ -21,8 +21,8 @@ from repro.core import plan as plan_lib
 from repro.core.schedules import DiceConfig
 from repro.core.staleness import (MoELayerState, init_planned_states,
                                   reset_slots)
-from repro.launch.serve import (DiceServer, Request, request_noise,
-                                serve_continuous)
+from repro.launch.serve import (DiceServer, Request, _admit_lanes,
+                                request_noise, serve_continuous)
 from repro.models.dit_moe import init_dit
 from repro.sampling.rectified_flow import make_rf_step
 
@@ -177,6 +177,47 @@ def test_reset_slots_zeroes_only_recycled_rows():
     np.testing.assert_array_equal(np.asarray(new[0].h_cache[:4]), 0.0)
     np.testing.assert_array_equal(np.asarray(new[0].h_cache[4:]), 3.0)
     assert new[1].x_prev is None and new[1].h_cache is None
+
+
+@pytest.mark.parametrize("mask", [[True, False, False, True],
+                                  [True] * 4, [False] * 4],
+                         ids=["partial", "full", "empty"])
+def test_admit_lanes_matches_the_eager_admission(mask):
+    """The admission program gives, bit for bit, what the eager surgery
+    gives: each recycled lane's request_noise written into x, and
+    reset_slots on both state sets.  The rids of other lanes are
+    ignored, and the inputs are donated (so copies go in)."""
+    cfg = tiny().replace(patch_tokens=8)
+    B, T, C = len(mask), cfg.patch_tokens, cfg.in_channels
+    ks = iter(jax.random.split(jax.random.PRNGKey(7), 16))
+
+    def leaf(*shape):
+        return jax.random.normal(next(ks), shape)
+
+    def state_set():
+        return {0: MoELayerState(y_buf=leaf(B * T, 6), x_prev=leaf(B * T, 6),
+                                 h_cache=leaf(B * T, 2, 6),
+                                 c_base=leaf(B * T, 6)),
+                1: MoELayerState(y_buf=leaf(B * T, 6),
+                                 h_cache=leaf(B * T, 2, 6))}
+
+    noise_key = next(ks)
+    x, states, states_u = leaf(B, T, C), state_set(), state_set()
+    recycle = np.asarray(mask)
+    rids = np.asarray([11, 3, 2 ** 31 + 5, 7], np.uint32)
+    want_x = x
+    for i in np.flatnonzero(recycle):
+        want_x = want_x.at[i].set(request_noise(noise_key, int(rids[i]),
+                                                cfg))
+    want = [reset_slots(s, jnp.asarray(recycle), tokens_per_slot=T)
+            for s in (states, states_u)]
+    args = jax.tree.map(jnp.copy, (x, states, states_u))
+    got_x, *got = _admit_lanes(*args, recycle, rids, noise_key)
+    assert all(a.is_deleted() for a in jax.tree.leaves(args))
+    np.testing.assert_array_equal(np.asarray(got_x), np.asarray(want_x))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_paper_comm_fraction_band():
