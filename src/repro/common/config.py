@@ -60,6 +60,9 @@ class ModelConfig:
     experts_per_token: int = 0
     num_shared_experts: int = 0
     moe_d_ff: int = 0                # expert hidden size (qwen3-moe: 768)
+    shared_d_ff: Optional[int] = None  # the shared experts' fused FFN width
+    #                                  (DiT-MoE: num_shared_experts x d_model);
+    #                                  None: num_shared_experts x expert_d_ff
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
 
@@ -106,6 +109,13 @@ class ModelConfig:
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
 
+    @property
+    def shared_width(self) -> int:
+        """Hidden width of the shared experts, fused into one gated FFN."""
+        if self.shared_d_ff is not None:
+            return self.shared_d_ff
+        return self.expert_d_ff * self.num_shared_experts
+
     def param_count(self) -> int:
         """Analytic parameter count (used for 6ND model-FLOPs)."""
         d = self.d_model
@@ -114,7 +124,8 @@ class ModelConfig:
         attn = d * n_q + 2 * d * n_kv + n_q * d if self.num_heads else 0
         if self.is_moe:
             ffn = 3 * d * self.expert_d_ff * self.num_experts
-            ffn += 3 * d * self.expert_d_ff * self.num_shared_experts
+            if self.num_shared_experts:
+                ffn += 3 * d * self.shared_width
             ffn += d * self.num_experts            # router
         else:
             ffn = 3 * d * self.d_ff
@@ -145,7 +156,9 @@ class ModelConfig:
         n_q = self.num_heads * self.head_dim
         n_kv = self.num_kv_heads * self.head_dim
         attn = d * n_q + 2 * d * n_kv + n_q * d if self.num_heads else 0
-        ffn = 3 * d * self.expert_d_ff * (self.experts_per_token + self.num_shared_experts)
+        ffn = 3 * d * self.expert_d_ff * self.experts_per_token
+        if self.num_shared_experts:
+            ffn += 3 * d * self.shared_width
         per_layer = attn + ffn + d * self.num_experts
         return int(self.num_layers * per_layer + 2 * self.vocab_size * d)
 

@@ -1,7 +1,8 @@
 """DiT-MoE-G — the paper's larger configuration (Sec. 5.1).
 
 Source: DiT-MoE [arXiv:2407.11633]; 40 layers, 16 experts top-2
-(+2 shared), d_model 1408.
+(+2 shared), d_model 1408.  The two shared experts are one gated FFN of
+width 2 x d_model (DiT-MoE's ``SparseMoeBlock``): 16.5 B parameters.
 """
 from repro.common.config import ModelConfig
 
@@ -12,7 +13,8 @@ def config() -> ModelConfig:
         num_layers=40, d_model=1408, d_ff=5632, vocab_size=0,
         num_heads=16, num_kv_heads=16, head_dim=88,
         num_experts=16, experts_per_token=2, num_shared_experts=2,
-        moe_d_ff=5632, patch_tokens=256, num_classes=1000, in_channels=16,
+        moe_d_ff=5632, shared_d_ff=2816, patch_tokens=256,
+        num_classes=1000, in_channels=16,
         source="arXiv:2407.11633",
     )
 
@@ -23,4 +25,5 @@ def smoke() -> ModelConfig:
         name="dit-moe-g-smoke", num_layers=2, d_model=128, d_ff=256,
         num_heads=4, num_kv_heads=4, head_dim=32, num_experts=4,
         experts_per_token=2, num_shared_experts=1, moe_d_ff=128,
+        shared_d_ff=None,
         patch_tokens=16, num_classes=8, in_channels=4, dtype="float32")

@@ -55,7 +55,7 @@ def moe_init(key, cfg: ModelConfig, *, dtype=jnp.bfloat16):
         "experts_down": dense_init(ks[3], (E, f, d), dtype=dtype),
     }
     if cfg.num_shared_experts:
-        fs = f * cfg.num_shared_experts
+        fs = cfg.shared_width
         p["shared_gate"] = dense_init(ks[4], (d, fs), dtype=dtype)
         p["shared_up"] = dense_init(ks[5], (d, fs), dtype=dtype)
         p["shared_down"] = dense_init(ks[6], (fs, d), dtype=dtype)

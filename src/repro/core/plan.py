@@ -275,6 +275,19 @@ class StepPlan:
     def num_sync_layers(self) -> int:
         return sum(a.mode == "sync" for a in self.actions)
 
+    @property
+    def kind(self) -> str:
+        """The variant by what it sends: "warmup", "sync" (every layer
+        synchronous after warm-up), "light" (some layer sends fewer pairs
+        than it routes: Conditional Communication) or "refresh"."""
+        if self.is_warmup:
+            return "warmup"
+        if any(a.mask_policy is not None for a in self.actions):
+            return "light"
+        if all(a.mode == "sync" for a in self.actions):
+            return "sync"
+        return "refresh"
+
 
 @dataclass(frozen=True)
 class SchedulePlan:

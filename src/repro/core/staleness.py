@@ -132,8 +132,8 @@ def shard_states(states, mesh, *, ep_axis="ep",
                  patch_axis: Optional[str] = None):
     """Place staleness state on ``mesh`` under :func:`state_specs`.
 
-    Used at init and after any host-side surgery (e.g. the continuous
-    engine's :func:`reset_slots` at admission) so the jitted step always
+    Used at init and after any eager surgery (e.g. the continuous
+    engine's :func:`reset_slots` at quarantine) so the jitted step always
     sees one stable input sharding — a changed layout would otherwise key
     a fresh jit-cache entry and break the compile-count guarantee.
     """

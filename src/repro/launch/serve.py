@@ -22,7 +22,7 @@ import contextlib
 import dataclasses
 import time
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 from typing import List, Optional
 
 import jax
@@ -727,8 +727,7 @@ def _lane_noise(key, rid, shape) -> jnp.ndarray:
     return jax.random.normal(jax.random.fold_in(key, rid), shape)
 
 
-@partial(jax.jit, donate_argnums=(0, 1, 2))
-def _admit_lanes(x, states, states_u, recycle, rids, noise_key):
+def _admit_surgery(x, states, states_u, recycle, rids, noise_key):
     """A cohort's admission surgery as one program: every recycled lane
     of ``x`` (B, T, C) takes its request's :func:`request_noise`, and its
     rows of both staleness state sets are zeroed by
@@ -745,6 +744,19 @@ def _admit_lanes(x, states, states_u, recycle, rids, noise_key):
     x = jnp.where(recycle[:, None, None], noise, x)
     return (x, stale_lib.reset_slots(states, recycle, tokens_per_slot=T),
             stale_lib.reset_slots(states_u, recycle, tokens_per_slot=T))
+
+
+_admit_lanes = jax.jit(_admit_surgery, donate_argnums=(0, 1, 2))
+
+
+@lru_cache(maxsize=8)
+def _admit_lanes_on(batch_sharding):
+    """:func:`_admit_lanes` for a mesh: every output pinned to
+    ``batch_sharding`` (lanes, and the state rows that follow them, over
+    the batch axes), the layout the mesh step takes, so nothing is
+    re-placed after it."""
+    return jax.jit(_admit_surgery, donate_argnums=(0, 1, 2),
+                   out_shardings=batch_sharding)
 
 
 def serve_continuous(server: "DiceServer", requests: List[Request], *,
@@ -781,19 +793,24 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     occupancy, and the aggregate byte/compile stats.
 
     ``mesh`` (default: the server's mesh) runs every tick mesh-native:
-    slots shard over the ``"ep"`` axis, the recycled-slot state surgery is
-    re-placed with ``staleness.shard_states`` so the jitted step always
-    sees one stable input layout, and the compile-count guarantee (jit
-    cache == plan-variant count) carries over to the sharded path.
+    slots shard over the ``"ep"`` axis, the admission program's outputs
+    are pinned to that layout (:func:`_admit_lanes_on`) so the jitted
+    step always sees one stable input layout, and the compile-count
+    guarantee (jit cache == plan-variant count) carries over to the
+    sharded path.
 
     With ``server.tracer`` set, every tick's host work is spanned, in
     order: ``serve.admit`` (a tick that admits; args ``tick``,
     ``admitted``, ``reset_lanes``: the lanes the admission program
     seeded and zeroed), ``serve.prepare`` (plan, slot masks, step
-    inputs), the ``tick`` span around ``serve.dispatch`` (the step's
-    launch) and ``serve.wait`` (``block_until_ready``),
-    ``serve.readback`` (the aux read-back and what consumes it; ``reads``
-    counts its blocking device->host reads), ``serve.quarantine``
+    inputs), the ``tick`` span (args ``tick``, ``slotted``,
+    ``variant``: the plan's :attr:`~repro.core.plan.StepPlan.kind`, or
+    "slotted") around ``serve.dispatch`` (the step's launch) and
+    ``serve.wait`` (``block_until_ready``), ``serve.readback`` (the aux
+    read-back and what consumes it; ``reads`` counts its blocking
+    device->host reads, ``wire_bytes``/``raw_bytes`` the tick's one-way
+    per-device dispatch payload over all its MoE calls, as sent and
+    uncompressed), ``serve.quarantine``
     (resilience on) and ``serve.complete`` (a tick where a request
     finishes).  A request's ``admit`` and ``done`` instants share its
     ``rid``.
@@ -854,6 +871,7 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     noise_key, step_key = jax.random.split(key)
     B, Tp = max_batch, cfg.patch_tokens
     dt = 1.0 / num_steps
+    n_pass = 2 if guidance != 1.0 else 1     # MoE forwards per step
     k_exp = cfg.experts_per_token
     b_dim = None
     if mesh is not None:
@@ -867,6 +885,11 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                              f"{n_batch}-way {bax} batch axes")
         bsp = shard_lib.hier_batch_spec(mesh)
         b_dim = bsp[0] if len(bsp) else None
+    # on a mesh the admission program's outputs are pinned to the layout
+    # the step takes (lanes and state rows over the batch axes): a drifted
+    # layout would key extra jit-cache entries of the step
+    admit_lanes = (_admit_lanes if mesh is None else _admit_lanes_on(
+        jax.sharding.NamedSharding(mesh, bsp)))
 
     def _place(a):
         """Pin the batch to its dp x ep sharding after host-side slot
@@ -1058,19 +1081,11 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                         tracer.instant("shed", args={"rid": rid,
                                                      "tick": tick})
                 if recycle.any():
-                    x, states, states_u = _admit_lanes(
+                    x, states, states_u = admit_lanes(
                         x, states, states_u, recycle, rids, noise_key)
                     adm["reset_lanes"] = int(recycle.sum())
                     reg.counter("dice_fused_admissions_total",
                                 "admission programs launched", lab).inc()
-                    if mesh is not None:
-                        # re-place after the surgery: a drifted layout
-                        # would key extra jit-cache entries
-                        states = stale_lib.shard_states(states, mesh,
-                                                        ep_axis=b_dim)
-                        states_u = stale_lib.shard_states(states_u, mesh,
-                                                          ep_axis=b_dim)
-                        x = _place(x)
         if not any(s.active for s in slots):
             nxt = queue.next_arrival()
             if nxt is None:
@@ -1114,7 +1129,9 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
             n_compiled = rf_step._cache_size()
 
         t_tick = time.perf_counter()
-        with _span(tracer, "tick", {"tick": tick, "slotted": bool(slotted)},
+        with _span(tracer, "tick", {"tick": tick, "slotted": bool(slotted),
+                                    "variant": ("slotted" if slotted
+                                                else plan.kind)},
                    cat="step"):
             # the host's launch of the step: argument flattening and
             # enqueue (a compile, on a variant's first tick)
@@ -1189,12 +1206,16 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                                     "in-graph wire corruption / guard events",
                                     {**lab, "event": nm}).inc(float(fe[idx]))
             hist.update(_read(aux["expert_counts"], rb))
+            wire = float(_read(aux["dispatch_bytes"], rb))
+            raw = float(_read(aux["raw_dispatch_bytes"], rb))
             reg.counter("dice_dispatch_bytes_total",
-                        "dispatch payload moved",
-                        lab).inc(float(_read(aux["dispatch_bytes"], rb)))
+                        "dispatch payload moved", lab).inc(wire)
             reg.counter("dice_raw_bytes_total",
-                        "lossless-equivalent payload bytes",
-                        lab).inc(float(_read(aux["raw_dispatch_bytes"], rb)))
+                        "lossless-equivalent payload bytes", lab).inc(raw)
+            # aux holds the conditional pass's payload; a guided step's
+            # unconditional pass runs the same plan and moves as much
+            rb["wire_bytes"] = wire * n_pass
+            rb["raw_bytes"] = raw * n_pass
             reg.counter("dice_hop_bytes_total",
                         "per-device one-hop ring wire",
                         lab).inc(float(_read(aux["hop_bytes"], rb)))

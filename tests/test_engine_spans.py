@@ -154,7 +154,7 @@ def test_tick_and_admit_args_as_the_benchmark_reads_them(traced):
     ev = traced["events"]
     for e in _named(ev, "tick"):
         assert e["ph"] == "X" and e["cat"] == "step"
-        assert set(e["args"]) == {"tick", "slotted"}
+        assert set(e["args"]) == {"tick", "slotted", "variant"}
         assert isinstance(e["args"]["tick"], int)
         assert isinstance(e["args"]["slotted"], bool)
     assert [e["args"]["tick"] for e in _named(ev, "tick")] == list(
@@ -164,6 +164,37 @@ def test_tick_and_admit_args_as_the_benchmark_reads_them(traced):
         assert set(e["args"]) == {"rid", "slot", "tick", "recycled"}
         assert all(isinstance(e["args"][k], int)
                    for k in ("rid", "slot", "tick"))
+
+
+def test_tick_names_its_variant_and_readback_its_wire_bytes(traced):
+    """The ``tick`` span names the plan variant that ran; the
+    ``serve.readback`` span carries the tick's one-way dispatch payload
+    over both guided forwards, which is the plan's, and a light step
+    (each token's first pair only) sends less than a refresh step."""
+    cfg = traced["server"].cfg
+    dcfg = DiceConfig.dice()
+    k = cfg.experts_per_token
+    splan = plan_lib.compile_step_plans(dcfg, cfg.num_layers, NUM_STEPS,
+                                        experts_per_token=k)
+    merge = plan_lib.slotted_merge_plan(dcfg, cfg.num_layers,
+                                        experts_per_token=k)
+    tokens = B * cfg.patch_tokens
+    planned = {p.kind: 2 * sum(a.dispatch_bytes(tokens, cfg)
+                               for a in p.actions) for p in splan.variants}
+    planned["slotted"] = 2 * sum(a.dispatch_bytes(tokens, cfg)
+                                 for a in merge.actions)
+    ev = traced["events"]
+    variant = {e["args"]["tick"]: e["args"]["variant"]
+               for e in _named(ev, "tick")}
+    # lockstep cohorts of NUM_STEPS: two warm-up ticks (slotted), then
+    # a refresh and a light step
+    assert [variant[t] for t in sorted(variant)] == [
+        "slotted", "slotted", "refresh", "light"] * 2
+    for e in _named(ev, "serve.readback"):
+        v = variant[e["args"]["tick"]]
+        assert e["args"]["wire_bytes"] == planned[v], (v, e["args"])
+        assert e["args"]["raw_bytes"] == e["args"]["wire_bytes"]
+    assert planned["light"] < planned["refresh"] == planned["slotted"]
 
 
 def test_readback_counts_its_device_reads(traced):

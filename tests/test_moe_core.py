@@ -151,3 +151,47 @@ def test_expert_parallel_matches_single_device():
                        text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
                                        "HOME": "/root"}, cwd="/root/repo")
     assert "EP-OK" in r.stdout, r.stderr[-2000:]
+
+
+@pytest.mark.parametrize("shared_d_ff", [None, 256])
+def test_shared_d_ff_sets_the_shared_ffn_width(shared_d_ff):
+    """``shared_d_ff`` is the shared experts' fused width in the weights
+    and in both parameter counts; unset, the width stays
+    ``num_shared_experts x expert_d_ff``."""
+    cfg = _cfg(num_shared_experts=2, shared_d_ff=shared_d_ff)
+    fs = 2 * 96 if shared_d_ff is None else shared_d_ff
+    assert cfg.shared_width == fs
+    p = jax.eval_shape(lambda k: moe_init(k, cfg), jax.random.PRNGKey(0))
+    assert p["shared_gate"].shape == (64, fs)
+    assert p["shared_up"].shape == (64, fs)
+    assert p["shared_down"].shape == (fs, 64)
+    base = _cfg(num_shared_experts=0)
+    d, L = cfg.d_model, cfg.num_layers
+    assert cfg.param_count() - base.param_count() == L * 3 * d * fs
+    assert (cfg.active_param_count() - base.active_param_count()
+            == L * 3 * d * fs)
+
+
+@pytest.mark.parametrize("name,shared,total", [
+    ("dit_moe_g", 2816, 16.5e9), ("dit_moe_xl", 2304, 4.17e9)])
+def test_published_dit_moe_sizes(name, shared, total):
+    """The published configs build the published models: the shared
+    experts one gated FFN of width 2 x d_model (DiT-MoE's
+    ``SparseMoeBlock``), DiT-MoE-G at 16.5 B parameters, XL at 4.17 B,
+    counted over the weights ``init_dit`` makes (shapes only)."""
+    import importlib
+    from repro.models.dit_moe import init_dit
+    cfg = importlib.import_module(f"repro.configs.{name}").config()
+    assert cfg.shared_d_ff == shared == 2 * cfg.d_model
+    tree = jax.eval_shape(lambda k: init_dit(k, cfg), jax.random.PRNGKey(0))
+    assert tree["blocks"][0]["moe"]["shared_down"].shape == (shared,
+                                                              cfg.d_model)
+    n = sum(a.size for a in jax.tree.leaves(tree))
+    assert abs(n - total) <= 0.01 * total, n
+
+
+def test_smoke_configs_keep_the_default_shared_width():
+    from repro.configs import dit_moe_g, dit_moe_xl
+    for cfg in (dit_moe_g.smoke(), dit_moe_xl.smoke(), dit_moe_xl.tiny()):
+        assert cfg.shared_d_ff is None
+        assert cfg.shared_width == cfg.num_shared_experts * cfg.moe_d_ff

@@ -323,3 +323,16 @@ def test_all_schedules_cache_equals_variants():
         _, stats = rf_sample(params, cfg, dcfg, num_steps=8, classes=classes,
                              key=jax.random.PRNGKey(3))
         assert stats["jit_cache_size"] == stats["num_plan_variants"], name
+
+
+def test_step_plan_kind_names_each_variant():
+    def kinds(dcfg):
+        return [p.kind for p in plan_lib.compile_step_plans(
+            dcfg, 4, 6, experts_per_token=2).steps]
+
+    assert kinds(DiceConfig.dice()) == ["warmup", "warmup", "refresh",
+                                        "light", "refresh", "light"]
+    selective = DiceConfig(schedule=Schedule.DICE, sync_policy="deep",
+                           cond_comm=False)
+    assert kinds(selective)[2:] == ["refresh"] * 4
+    assert kinds(DiceConfig.sync_ep()) == ["sync"] * 6
