@@ -21,7 +21,7 @@ import argparse
 import contextlib
 import dataclasses
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import lru_cache, partial
 from typing import List, Optional
 
@@ -705,6 +705,26 @@ class _Slot:
     active: bool = False
 
 
+@dataclass
+class _Launched:
+    """An engine tick from its launch until the host has read it back.
+
+    What its read-back and completion need is taken at launch from the
+    host's own counters: the free lanes, the queue depth, and the lanes
+    that finish with it (``finished``: lane, rid and the sample sliced
+    from the tick's latents as a device op).  ``wall`` is set when the
+    host first sees the tick ready."""
+    tick: int
+    slotted: bool
+    variant: str
+    n_free: int
+    queue_len: int
+    t_launch: float
+    aux: Optional[dict] = None
+    wall: Optional[float] = None
+    finished: list = field(default_factory=list)
+
+
 def request_noise(key, rid: int, cfg: ModelConfig) -> jnp.ndarray:
     """Per-request initial latent noise: (patch_tokens, in_channels).
 
@@ -799,20 +819,47 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     guarantee (jit cache == plan-variant count) carries over to the
     sharded path.
 
+    One tick is kept in flight: tick t+1 is launched before the host
+    reads back and completes tick t, so the host's per-tick work runs
+    while the device computes.  Tick t+1's inputs come from the host's
+    step counters and tick t's unready outputs; a finishing lane's
+    sample is sliced on the device as its last tick is launched, before
+    the next admission donates the latents.  The engine drops to no
+    tick in flight when a policy acts on a tick's outputs before the
+    next tick is decided: resilience (the watchdog reads the tick's
+    wall time, quarantine scans ``x``), online placement (it re-shards
+    from the routing histogram) and paging (its fetches ride inside the
+    step).  A variant's first tick, whose launch compiles, also runs at
+    depth 0.  ``stats["pipelined_ticks"]`` counts the ticks launched while
+    the previous tick was still unread; ``stats["tick_s"]`` holds
+    seconds between successive ready times.  The step donates both
+    state sets (:func:`~repro.sampling.rectified_flow.make_rf_step`), so
+    a tick in flight holds no third generation of the staleness
+    buffers.
+
     With ``server.tracer`` set, every tick's host work is spanned, in
     order: ``serve.admit`` (a tick that admits; args ``tick``,
     ``admitted``, ``reset_lanes``: the lanes the admission program
     seeded and zeroed), ``serve.prepare`` (plan, slot masks, step
-    inputs), the ``tick`` span (args ``tick``, ``slotted``,
-    ``variant``: the plan's :attr:`~repro.core.plan.StepPlan.kind`, or
-    "slotted") around ``serve.dispatch`` (the step's launch) and
+    inputs), ``serve.dispatch`` (the step's launch; arg ``tick``),
     ``serve.wait`` (``block_until_ready``), ``serve.readback`` (the aux
-    read-back and what consumes it; ``reads`` counts its blocking
-    device->host reads, ``wire_bytes``/``raw_bytes`` the tick's one-way
-    per-device dispatch payload over all its MoE calls, as sent and
-    uncompressed), ``serve.quarantine``
-    (resilience on) and ``serve.complete`` (a tick where a request
-    finishes).  A request's ``admit`` and ``done`` instants share its
+    read-back of the tick just seen ready and what consumes it; ``tick``
+    names it,
+    ``reads`` counts its blocking device->host reads,
+    ``wire_bytes``/``raw_bytes`` the tick's one-way per-device dispatch
+    payload over all its MoE calls, as sent and uncompressed),
+    ``serve.quarantine`` (resilience on) and ``serve.complete`` (a tick
+    where a request finishes).  A ``tick`` span (args ``tick``,
+    ``slotted``, ``variant``: the plan's
+    :attr:`~repro.core.plan.StepPlan.kind`, or "slotted";
+    ``pipelined``: the next tick's launch is in it) ends as the host
+    sees the tick it names ready and holds that ``serve.wait``.  With no
+    tick in flight it also holds the tick's own launch; with one in
+    flight it holds the next tick's launch instead, the launch that
+    starts a run of pipelined ticks lies outside every ``tick`` span,
+    and a tick drained with no launch after it (the last one, one
+    before the engine idles or before a depth-0 tick) holds only its
+    wait.  A request's ``admit`` and ``done`` instants share its
     ``rid``.
     """
     cfg, dcfg = server.cfg, server.dcfg
@@ -920,8 +967,10 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                                    ep_axis=ep_axis,
                                    hop_schedule=server.hop_schedule,
                                    expert_pool=pool, obs=server.obs)
+        launched_variants.clear()
         return splan, merge_plan, rf_step
 
+    launched_variants: set = set()    # (plan, slotted) run by this step fn
     splan, merge_plan, rf_step = _build(dcfg)
     period = plan_lib.steady_period(dcfg, cfg.num_layers,
                                     experts_per_token=k_exp)
@@ -935,6 +984,10 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     n_place = n_ep
     place_online = (pcfg is not None and pcfg.mode == "greedy"
                     and n_place > 1)
+    # one tick in flight, unless a policy acts on a tick's outputs before
+    # the next tick is decided: the watchdog and quarantine (resilience),
+    # the online re-shard (the routing histogram), paging (its fetches)
+    pipelined = res is None and not place_online and pool is None
     hist = placement_lib.RoutingHistogram(
         cfg.num_layers, cfg.num_experts,
         decay=pcfg.ema_decay if pcfg is not None else 0.9)
@@ -960,14 +1013,154 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
         queue.push(0.0 if arrival_steps is None else float(arrival_steps[i]),
                    r)
     out: dict = {}
-    compile_s: dict = {}     # variant label -> its first tick's seconds
+    compile_s: dict = {}     # variant label -> seconds of its compiling tick
     tick_s: list = []        # seconds of every tick that compiled nothing
+    compiling: list = []     # variants compiled since a tick was last seen
+    inflight: Optional[_Launched] = None   # launched, not yet read back
+    t_ready: Optional[float] = None        # the last tick seen ready
     tick = 0
     t0 = time.perf_counter()
 
     def _next_aligned(g: float) -> int:
         g = int(np.ceil(g))
         return g + (-g) % period
+
+    def _seen(done: _Launched) -> None:
+        """The host sees ``done``'s tick ready: its seconds run from the
+        previous tick's ready time (one in flight), else from its launch;
+        a variant compiled meanwhile claims them."""
+        nonlocal t_ready
+        now = time.perf_counter()
+        done.wall = now - (done.t_launch if t_ready is None else t_ready)
+        for label in compiling:
+            compile_s[label] = done.wall
+        if not compiling:
+            tick_s.append(done.wall)
+        compiling.clear()
+        t_ready = now if pipelined else None
+
+    def _finish_lanes(launched: _Launched, x) -> None:
+        """Step every busy lane's counter past ``launched``'s tick; a lane
+        that finishes frees its slot, its sample sliced from ``x`` on
+        the device (read to the host by :func:`_complete`)."""
+        for i, slot in enumerate(slots):
+            if slot.active:
+                slot.local_step += 1
+                if slot.local_step >= num_steps:
+                    launched.finished.append((i, slot.rid, x[i]))
+                    slots[i] = _Slot()
+                    classes[i] = cfg.num_classes
+
+    def _readback(done: _Launched) -> None:
+        """The aux read-back of a tick seen ready, and the registry
+        updates that consume it."""
+        aux = done.aux
+        rb = {"tick": done.tick, "reads": 0}
+        with _span(tracer, "serve.readback", rb):
+            tel = None
+            if "telemetry" in aux and (obs_on or ctrl is not None):
+                tel = _read(aux["telemetry"], rb)
+            if obs_on:
+                reg.histogram("dice_step_wall_seconds",
+                              "measured wall seconds per engine tick",
+                              lab).observe(done.wall)
+                if tel is not None:
+                    _publish_telemetry_step(reg, tel, lab)
+            if ctrl is not None:
+                codec_err = (None if tel is None
+                             else float(tel[:, obs_fields.CODEC_ERR].mean()))
+                if ctrl.observe_step(done.wall, codec_err):
+                    reg.counter("dice_watchdog_breaches_total",
+                                "engine-tick step-deadline breaches",
+                                lab).inc()
+
+            reg.counter("dice_ticks_total", "engine ticks executed",
+                        lab).inc()
+            if done.slotted:
+                reg.counter("dice_slotted_ticks_total",
+                            "ticks on the slotted merge plan", lab).inc()
+            reg.counter("dice_padded_slot_steps_total",
+                        "free-slot step executions", lab).inc(done.n_free)
+            reg.series("dice_slot_occupancy", "active-slot fraction per tick",
+                       lab).append(1.0 - done.n_free / B)
+            reg.series("dice_queue_depth", "requests still waiting",
+                       lab).append(done.queue_len)
+            if "fault_events" in aux:
+                fe = _read(aux["fault_events"], rb)
+                for idx, nm in enumerate(("corrupt_combine",
+                                          "guarded_combine",
+                                          "corrupt_dispatch",
+                                          "guarded_dispatch")):
+                    if fe[idx]:
+                        reg.counter("dice_fault_events_total",
+                                    "in-graph wire corruption / guard events",
+                                    {**lab, "event": nm}).inc(float(fe[idx]))
+            hist.update(_read(aux["expert_counts"], rb))
+            wire = float(_read(aux["dispatch_bytes"], rb))
+            raw = float(_read(aux["raw_dispatch_bytes"], rb))
+            reg.counter("dice_dispatch_bytes_total",
+                        "dispatch payload moved", lab).inc(wire)
+            reg.counter("dice_raw_bytes_total",
+                        "lossless-equivalent payload bytes", lab).inc(raw)
+            # aux holds the conditional pass's payload; a guided step's
+            # unconditional pass runs the same plan and moves as much
+            rb["wire_bytes"] = wire * n_pass
+            rb["raw_bytes"] = raw * n_pass
+            reg.counter("dice_hop_bytes_total",
+                        "per-device one-hop ring wire",
+                        lab).inc(float(_read(aux["hop_bytes"], rb)))
+            reg.gauge("dice_ring_hops",
+                      "ring collective-permutes per MoE layer",
+                      lab).set_max(int(_read(aux["hops"], rb)))
+            reg.gauge("dice_buffer_bytes",
+                      "persistent staleness-buffer footprint",
+                      lab).set(int(_read(aux["buffer_bytes"], rb)))
+
+    def _complete(done: _Launched) -> None:
+        """The finished lanes' samples to the host, one read for all."""
+        if not done.finished:
+            return
+        with _span(tracer, "serve.complete",
+                   {"tick": done.tick, "finished": len(done.finished)}):
+            samples = jax.device_get([s for _, _, s in done.finished])
+            for (i, rid, _), sample in zip(done.finished, samples):
+                out[rid] = sample
+                reg.counter("dice_requests_total", "requests served",
+                            lab).inc()
+                if rid in admit_time:
+                    reg.histogram(
+                        "dice_request_service_seconds",
+                        "request service seconds (admission->sample; "
+                        "the queue wait is not in it)",
+                        lab).observe(time.perf_counter()
+                                     - admit_time.pop(rid))
+                if tracer is not None:
+                    tracer.instant("done", args={
+                        "rid": rid, "slot": i, "tick": done.tick})
+
+    def _tick_span(done: Optional[_Launched], holds_next: bool):
+        """The ``tick`` span of ``done``: it ends as the host sees that
+        tick ready.  ``pipelined``: the next tick's launch is in it."""
+        if done is None:
+            return contextlib.nullcontext()
+        return _span(tracer, "tick", {"tick": done.tick,
+                                      "slotted": bool(done.slotted),
+                                      "variant": done.variant,
+                                      "pipelined": holds_next}, cat="step")
+
+    def _drain() -> None:
+        """Wait for the tick in flight, then read it back and complete
+        it: after the last launch, and before the engine idles."""
+        nonlocal inflight, t_ready
+        if inflight is None:
+            return
+        with _tick_span(inflight, holds_next=False):
+            with _span(tracer, "serve.wait"):
+                jax.block_until_ready(inflight.aux)
+            _seen(inflight)
+        _readback(inflight)
+        _complete(inflight)
+        inflight, t_ready = None, None
 
     while len(queue) or any(s.active for s in slots):
         # ---- watchdog variant demotion at aligned boundaries (Sec. 17) ---
@@ -1087,6 +1280,7 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                     reg.counter("dice_fused_admissions_total",
                                 "admission programs launched", lab).inc()
         if not any(s.active for s in slots):
+            _drain()
             nxt = queue.next_arrival()
             if nxt is None:
                 break          # everything remaining was shed
@@ -1125,21 +1319,45 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                 slot_fresh = consume = None
             t = jnp.asarray([s.local_step * dt if s.active else 0.0
                              for s in slots], jnp.float32)
-            cls = jnp.asarray(classes)
+            # a copy: the host edits ``classes`` in place while this tick
+            # is in flight, and on the CPU ``asarray`` may alias it
+            cls = jnp.array(classes)
             n_compiled = rf_step._cache_size()
 
-        t_tick = time.perf_counter()
-        with _span(tracer, "tick", {"tick": tick, "slotted": bool(slotted),
-                                    "variant": ("slotted" if slotted
-                                                else plan.kind)},
-                   cat="step"):
+        # a variant's first launch compiles, which outlasts a tick in
+        # flight: that tick runs at depth 0, so its span holds its compile
+        # and its first run, as a compiling tick's span did before
+        first_run = (plan, slotted) not in launched_variants
+        launched_variants.add((plan, slotted))
+        depth0 = not pipelined or first_run
+        if depth0:
+            _drain()
+        launched = _Launched(tick=tick, slotted=slotted,
+                             variant="slotted" if slotted else plan.kind,
+                             n_free=sum(not s.active for s in slots),
+                             queue_len=len(queue),
+                             t_launch=time.perf_counter())
+        if inflight is not None:
+            reg.counter("dice_pipelined_ticks_total",
+                        "ticks launched while the previous tick was "
+                        "still unread", lab).inc()
+        # the tick this launch's span waits for and ends on: this one at
+        # depth 0, else the one in flight; none for the launch that
+        # starts a run of pipelined ticks
+        ready = launched if depth0 else inflight
+        with _tick_span(ready, holds_next=inflight is not None):
             # the host's launch of the step: argument flattening and
             # enqueue (a compile, on a variant's first tick)
-            with _span(tracer, "serve.dispatch"):
-                x, states, states_u, _, _, aux = rf_step(
+            with _span(tracer, "serve.dispatch", {"tick": tick}):
+                x, states, states_u, _, _, launched.aux = rf_step(
                     x, cls, states, states_u, {}, {}, t, tick_key,
                     plan=plan, slotted=slotted, slot_fresh=slot_fresh,
                     consume_mask=consume)
+            if rf_step._cache_size() > n_compiled:
+                # first tick of a (plan, slotted) variant: trace + compile,
+                # kept out of the steady per-tick times
+                compiling.append("slotted" if slotted else
+                                 f"v{splan.variant_of_step[plan_idx]}")
             if (fplan is not None and plan_lib.overlap_of(dcfg)
                     and fplan.hop_delay(tick)):
                 # injected slow ring hop (Sec. 17): host-visible, so the
@@ -1149,82 +1367,26 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                 reg.counter("dice_injected_hop_delays_total",
                             "injected slow ring hops", lab).inc()
                 time.sleep(fplan.cfg.hop_delay_s)
-            # measured (not modeled) per-tick walltime.  The tick already
-            # waits for the step when it reads aux back below, so this
-            # sync costs no overlap.
-            with _span(tracer, "serve.wait"):
-                jax.block_until_ready(x)
-        wall = time.perf_counter() - t_tick
+            # measured (not modeled) per-tick walltime, read through the
+            # tick's aux (this tick's admission donated the latents of the
+            # tick in flight)
+            if ready is not None:
+                with _span(tracer, "serve.wait"):
+                    jax.block_until_ready(ready.aux)
+                _seen(ready)
 
-        # ---- aux read-back and the registry updates that consume it ------
-        rb = {"tick": tick, "reads": 0}
-        with _span(tracer, "serve.readback", rb):
-            if rf_step._cache_size() > n_compiled:
-                # first tick of a (plan, slotted) variant: trace + compile
-                # + one execution, kept out of the steady per-tick times
-                compile_s["slotted" if slotted else
-                          f"v{splan.variant_of_step[plan_idx]}"] = wall
-            else:
-                tick_s.append(wall)
-            tel = None
-            if "telemetry" in aux and (obs_on or ctrl is not None):
-                tel = _read(aux["telemetry"], rb)
-            if obs_on:
-                reg.histogram("dice_step_wall_seconds",
-                              "measured wall seconds per engine tick",
-                              lab).observe(wall)
-                if tel is not None:
-                    _publish_telemetry_step(reg, tel, lab)
-            if ctrl is not None:
-                codec_err = (None if tel is None
-                             else float(tel[:, obs_fields.CODEC_ERR].mean()))
-                if ctrl.observe_step(wall, codec_err):
-                    reg.counter("dice_watchdog_breaches_total",
-                                "engine-tick step-deadline breaches",
-                                lab).inc()
-
-            n_free = sum(not s.active for s in slots)
-            reg.counter("dice_ticks_total", "engine ticks executed",
-                        lab).inc()
-            if slotted:
-                reg.counter("dice_slotted_ticks_total",
-                            "ticks on the slotted merge plan", lab).inc()
-            reg.counter("dice_padded_slot_steps_total",
-                        "free-slot step executions", lab).inc(n_free)
-            reg.series("dice_slot_occupancy", "active-slot fraction per tick",
-                       lab).append(1.0 - n_free / B)
-            reg.series("dice_queue_depth", "requests still waiting",
-                       lab).append(len(queue))
-            if "fault_events" in aux:
-                fe = _read(aux["fault_events"], rb)
-                for idx, nm in enumerate(("corrupt_combine",
-                                          "guarded_combine",
-                                          "corrupt_dispatch",
-                                          "guarded_dispatch")):
-                    if fe[idx]:
-                        reg.counter("dice_fault_events_total",
-                                    "in-graph wire corruption / guard events",
-                                    {**lab, "event": nm}).inc(float(fe[idx]))
-            hist.update(_read(aux["expert_counts"], rb))
-            wire = float(_read(aux["dispatch_bytes"], rb))
-            raw = float(_read(aux["raw_dispatch_bytes"], rb))
-            reg.counter("dice_dispatch_bytes_total",
-                        "dispatch payload moved", lab).inc(wire)
-            reg.counter("dice_raw_bytes_total",
-                        "lossless-equivalent payload bytes", lab).inc(raw)
-            # aux holds the conditional pass's payload; a guided step's
-            # unconditional pass runs the same plan and moves as much
-            rb["wire_bytes"] = wire * n_pass
-            rb["raw_bytes"] = raw * n_pass
-            reg.counter("dice_hop_bytes_total",
-                        "per-device one-hop ring wire",
-                        lab).inc(float(_read(aux["hop_bytes"], rb)))
-            reg.gauge("dice_ring_hops",
-                      "ring collective-permutes per MoE layer",
-                      lab).set_max(int(_read(aux["hops"], rb)))
-            reg.gauge("dice_buffer_bytes",
-                      "persistent staleness-buffer footprint",
-                      lab).set(int(_read(aux["buffer_bytes"], rb)))
+        if not depth0:
+            # this tick's finishing lanes are sliced before the next
+            # admission donates x; the previous tick is read back while
+            # this one runs
+            _finish_lanes(launched, x)
+            if inflight is not None:
+                _readback(inflight)
+                _complete(inflight)
+            inflight = launched
+            tick += 1
+            continue
+        _readback(launched)
 
         # ---- slot quarantine (Sec. 17 rung 4) ----------------------------
         # a non-finite lane — corruption that escaped the wire guards, or
@@ -1285,34 +1447,10 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                         x = _place(x)
 
         # ---- completion: finished lanes' samples to the host --------------
-        finished = []
-        for i, slot in enumerate(slots):
-            if slot.active:
-                slot.local_step += 1
-                if slot.local_step >= num_steps:
-                    finished.append(i)
-        if finished:
-            with _span(tracer, "serve.complete",
-                       {"tick": tick, "finished": len(finished)}):
-                for i in finished:
-                    slot = slots[i]
-                    out[slot.rid] = np.asarray(x[i])
-                    reg.counter("dice_requests_total", "requests served",
-                                lab).inc()
-                    if slot.rid in admit_time:
-                        reg.histogram(
-                            "dice_request_service_seconds",
-                            "request service seconds (admission->sample; "
-                            "the queue wait is not in it)",
-                            lab).observe(
-                                time.perf_counter()
-                                - admit_time.pop(slot.rid))
-                    if tracer is not None:
-                        tracer.instant("done", args={
-                            "rid": slot.rid, "slot": i, "tick": tick})
-                    slots[i] = _Slot()
-                    classes[i] = cfg.num_classes
+        _finish_lanes(launched, x)
+        _complete(launched)
         tick += 1
+    _drain()
 
     # the latency model describes the REQUESTED deployment (server.dcfg,
     # un-normalized) but with whatever placements the run ended on — an
@@ -1382,6 +1520,7 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
         return reg.value(name, labels) if reg.get(name, labels) is not None \
             else 0.0
 
+    stats["pipelined_ticks"] = int(_cnt("dice_pipelined_ticks_total"))
     if res is not None:
         # resilience observability (Sec. 17): every rung's event counts,
         # all views of the same registry the tracer/metrics exports carry

@@ -161,6 +161,12 @@ def make_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
     ``params`` are never a closure constant: the jitted step takes them
     as its first argument and the returned :class:`BoundStep` passes
     them in on every call (see there).
+
+    ``states`` and ``states_u`` are donated: the step writes its new
+    staleness state over them, so a caller keeps one generation of the
+    buffers, also with a step in flight, and must not read them after
+    the call (it holds what the step returns).  The latents (a few
+    hundred KB) are not donated.
     """
     if mesh is not None:
         return _make_mesh_rf_step(
@@ -169,7 +175,8 @@ def make_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
             ep_axis=ep_axis or "ep", hop_schedule=hop_schedule,
             expert_pool=expert_pool, obs=obs)
 
-    @partial(jax.jit, static_argnames=("plan", "slotted"))
+    @partial(jax.jit, static_argnames=("plan", "slotted"),
+             donate_argnames=("states", "states_u"))
     def rf_step(params, x, classes, states, states_u, patch_states,
                 patch_states_u, t, key, *, plan, slotted=False,
                 slot_fresh=None, consume_mask=None, patch_fresh=None):
@@ -282,7 +289,8 @@ def _make_mesh_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
     params = shard_lib.ep_shard_params(params, mesh, ep_axis=live_ep)
     pspecs = shard_lib.ep_param_specs(params, ep_axis=live_ep)
 
-    @partial(jax.jit, static_argnames=("plan", "slotted"))
+    @partial(jax.jit, static_argnames=("plan", "slotted"),
+             donate_argnames=("states", "states_u"))
     def rf_step(params, x, classes, states, states_u, patch_states,
                 patch_states_u, t, key, *, plan, slotted=False,
                 slot_fresh=None, consume_mask=None, patch_fresh=None):

@@ -2,11 +2,13 @@
 profiler's clock, and the always-on device scopes of the model.
 
 A ``StepTracer`` on ``server.tracer`` must see, per tick: ``serve.admit``
-(a tick that admits), ``serve.prepare``, the ``tick`` span holding
-``serve.dispatch`` and ``serve.wait``, ``serve.readback`` and, where a
-request finishes, ``serve.complete`` with one ``done`` instant per
-request.  The benchmark reads the ``tick`` span and ``admit`` instant by
-name and args, so those are pinned here too.  Every span is also a
+(a tick that admits), ``serve.prepare``, ``serve.dispatch``, the ``tick``
+span ending as the host sees the tick ready (it holds the tick's
+``serve.wait`` and at most one launch: its own at depth 0, the next
+tick's with one in flight), ``serve.readback`` and, where a request
+finishes, ``serve.complete`` with one ``done`` instant per request.
+The benchmark reads the ``tick`` span and ``admit`` instant by name and
+args, so those are pinned here too.  Every span is also a
 ``jax.profiler.TraceAnnotation``: a profiler capture holds the names as
 host events.  The model's layer parts carry ``jax.named_scope`` names
 in their HLO metadata whatever the observability setting.
@@ -114,16 +116,63 @@ def test_admission_is_one_program_compiled_once():
                                 lab) == len(admits)
 
 
-def test_dispatch_and_wait_nest_in_each_tick(traced):
+@pytest.fixture(scope="module")
+def traced_depth0():
+    """A server whose policies read each tick before the next is
+    launched (resilience): no tick in flight."""
+    tracer = StepTracer()
+    (out, stats), server = _serve(tracer,
+                                  resilience=ResilienceConfig(guards=True))
+    return dict(out=out, stats=stats, events=list(tracer.events),
+                server=server)
+
+
+@pytest.mark.parametrize("run", ["traced", "traced_depth0"])
+def test_dispatch_and_wait_nest_in_each_tick(run, request):
+    """Every tick span holds one ``serve.wait`` and at most one launch
+    before it: its own tick's at depth 0 (always with resilience on; a
+    variant's first, compiling tick otherwise), the next tick's with one
+    in flight (``pipelined``).  A launch outside every tick span finds
+    no tick in flight.  A tick's span ends as the host sees it ready:
+    its read-back follows the span and comes before the next span."""
+    traced = request.getfixturevalue(run)
     ev = traced["events"]
-    for tk in _named(ev, "tick"):
-        for name in IN_TICK:
-            inner = [e for e in _named(ev, name) if _overlaps(e, tk)]
-            assert len(inner) == 1, (name, tk["args"])
-            assert _inside(inner[0], tk), (name, tk["args"])
-        d, w = (next(e for e in _named(ev, n) if _overlaps(e, tk))
-                for n in IN_TICK)
-        assert d["ts"] + d["dur"] <= w["ts"]      # launch, then the wait
+    depth0 = run == "traced_depth0"
+    ticks = _named(ev, "tick")
+    launches = _named(ev, "serve.dispatch")
+    assert sorted(e["args"]["tick"] for e in launches) == [
+        tk["args"]["tick"] for tk in ticks]
+    own = []
+    for tk in ticks:
+        n, pipelined = tk["args"]["tick"], tk["args"]["pipelined"]
+        (w,) = [e for e in _named(ev, "serve.wait") if _overlaps(e, tk)]
+        assert _inside(w, tk), tk["args"]
+        inner = [e for e in launches if _overlaps(e, tk)]
+        assert len(inner) <= 1 and len(inner) >= pipelined, tk["args"]
+        for d in inner:
+            assert _inside(d, tk), tk["args"]
+            assert d["ts"] + d["dur"] <= w["ts"]      # launch, then the wait
+            assert d["args"]["tick"] == n + pipelined, tk["args"]
+            if not pipelined:
+                own.append(n)
+    readback = {e["args"]["tick"]: e for e in _named(ev, "serve.readback")}
+    for tk, nxt in zip(ticks, ticks[1:] + [None]):
+        rb = readback[tk["args"]["tick"]]
+        assert tk["ts"] + tk["dur"] <= rb["ts"], tk["args"]
+        if nxt is not None:
+            assert rb["ts"] + rb["dur"] <= nxt["ts"], tk["args"]
+    for d in launches:
+        if not any(_overlaps(d, tk) for tk in ticks):
+            n = d["args"]["tick"]
+            assert n == 0 or readback[n - 1]["ts"] < d["ts"], n
+    pipelined = sum(tk["args"]["pipelined"] for tk in ticks)
+    assert pipelined == traced["stats"]["pipelined_ticks"]
+    if depth0:
+        assert pipelined == 0 and own == list(range(len(ticks)))
+    else:
+        # lockstep cohorts: ticks 0, 2 and 3 first run the slotted,
+        # refresh and light variants
+        assert own == [0, 2, 3] and pipelined == len(ticks) - 5
 
 
 def test_between_tick_spans_lie_outside_every_tick(traced):
@@ -154,9 +203,10 @@ def test_tick_and_admit_args_as_the_benchmark_reads_them(traced):
     ev = traced["events"]
     for e in _named(ev, "tick"):
         assert e["ph"] == "X" and e["cat"] == "step"
-        assert set(e["args"]) == {"tick", "slotted", "variant"}
+        assert set(e["args"]) == {"tick", "slotted", "variant", "pipelined"}
         assert isinstance(e["args"]["tick"], int)
         assert isinstance(e["args"]["slotted"], bool)
+        assert isinstance(e["args"]["pipelined"], bool)
     assert [e["args"]["tick"] for e in _named(ev, "tick")] == list(
         range(len(_named(ev, "tick"))))
     for e in _named(ev, "admit"):

@@ -353,3 +353,144 @@ def test_steady_period_and_merge_plan():
                                         experts_per_token=2)
     assert merge in splan.variants
     assert all(a.mask_policy is None for a in merge.actions)
+
+
+def _three_cohorts(cfg):
+    return [Request(class_id=(3 * i + 1) % cfg.num_classes, rid=i)
+            for i in range(6)]
+
+
+def test_one_tick_in_flight_over_three_cohorts(setup):
+    """The default server keeps one tick in flight: a tick is launched
+    while the previous one is still unread, except a variant's first
+    (compiling) tick, which runs at depth 0, and the tick after it; a
+    pipelined launch comes before the previous tick's read-back, and
+    every sample is bit-identical to the same cohort in a fresh fixed
+    batch."""
+    from repro.obs import StepTracer
+    cfg, params = setup
+    dcfg = DiceConfig.dice()
+    server = DiceServer(cfg, dcfg, params=params)
+    server.tracer = StepTracer()
+    reqs = _three_cohorts(cfg)
+    key = jax.random.PRNGKey(11)
+    out, stats = serve_continuous(server, reqs, max_batch=2, num_steps=4,
+                                  key=key)
+    assert stats["ticks"] == 12                     # 3 cohorts x 4 steps
+    # ticks 0, 2, 3 first run the slotted, refresh and light variants,
+    # ticks 0, 1, 2, 3, 4 find no tick in flight
+    assert stats["pipelined_ticks"] == stats["ticks"] - 5
+    lab = {"schedule": "dice", "engine": "continuous"}
+    assert server.metrics.value("dice_pipelined_ticks_total",
+                                lab) == stats["ticks"] - 5
+    assert len(stats["tick_s"]) + len(stats["compile_s"]) <= stats["ticks"]
+    assert len(stats["compile_s"]) == 3
+
+    ev = server.tracer.events
+    ticks = {e["args"]["tick"]: e for e in ev if e["name"] == "tick"}
+    assert sorted(ticks) == list(range(stats["ticks"]))
+    launch = {e["args"]["tick"]: e["ts"] for e in ev
+              if e["name"] == "serve.dispatch"}
+    readback = {e["args"]["tick"]: e["ts"] for e in ev
+                if e["name"] == "serve.readback"}
+    assert sorted(launch) == sorted(readback) == sorted(ticks)
+    pipelined = [t for t in sorted(ticks) if ticks[t]["args"]["pipelined"]]
+    assert pipelined == list(range(4, stats["ticks"] - 1))
+    for t in sorted(ticks)[:-1]:
+        # tick t's span ends as tick t is seen ready, before its read-back
+        assert ticks[t]["ts"] + ticks[t]["dur"] <= readback[t], t
+        if t in pipelined:
+            assert launch[t + 1] < readback[t], t
+        else:
+            assert launch[t + 1] > readback[t], t
+
+    for c in range(3):
+        cohort = reqs[2 * c:2 * c + 2]
+        ref = _fresh_batch(params, cfg, dcfg, cohort, num_steps=4, key=key)
+        for r in cohort:
+            np.testing.assert_array_equal(out[r.rid], ref[r.rid])
+
+
+def test_resilience_keeps_no_tick_in_flight(setup):
+    """A policy that reads each tick before the next is decided (here the
+    watchdog and quarantine) runs with no tick in flight, and serves the
+    same samples."""
+    from repro.resilience.faults import ResilienceConfig
+    cfg, params = setup
+    dcfg = DiceConfig.dice()
+    reqs = _three_cohorts(cfg)
+    key = jax.random.PRNGKey(11)
+    server = DiceServer(cfg, dcfg, params=params,
+                        resilience=ResilienceConfig(guards=True))
+    out, stats = serve_continuous(server, reqs, max_batch=2, num_steps=4,
+                                  key=key)
+    assert stats["pipelined_ticks"] == 0
+    lab = {"schedule": "dice", "engine": "continuous"}
+    assert server.metrics.get("dice_pipelined_ticks_total", lab) is None
+    ref, _ = serve_continuous(DiceServer(cfg, dcfg, params=params), reqs,
+                              max_batch=2, num_steps=4, key=key)
+    assert sorted(out) == sorted(ref)
+    for rid in ref:
+        np.testing.assert_array_equal(out[rid], ref[rid])
+
+
+def test_placement_and_paging_keep_no_tick_in_flight():
+    """On an 8-way ep mesh the default server keeps one tick in flight;
+    online placement (re-shards from each tick's routing histogram) and
+    paging (fetches ride inside the step) keep none, and online placement
+    serves the same samples.  Subprocess-based: the parent process must
+    keep the single real CPU device."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    prog = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        import jax
+        import numpy as np
+        from repro.configs.dit_moe_xl import tiny
+        from repro.core.paging import PagingSpec
+        from repro.core.placement import PlacementConfig
+        from repro.core.schedules import DiceConfig
+        from repro.launch.mesh import make_ep_mesh
+        from repro.launch.serve import DiceServer, Request, serve_continuous
+        from repro.models.dit_moe import init_dit
+
+        cfg = tiny().replace(num_layers=4, d_model=64, moe_d_ff=64,
+                             d_ff=256, patch_tokens=16, capacity_factor=8.0)
+        params = init_dit(jax.random.PRNGKey(0), cfg)
+        mesh = make_ep_mesh(8)
+        reqs = [Request(class_id=(3 * i + 1) % cfg.num_classes, rid=i)
+                for i in range(24)]
+        key = jax.random.PRNGKey(11)
+
+        def run(**kw):
+            server = DiceServer(cfg, DiceConfig.dice(), params=params,
+                                mesh=mesh, **kw)
+            return serve_continuous(server, reqs, max_batch=8, num_steps=4,
+                                    key=key)
+
+        out, stats = run()
+        # each variant's first tick runs at depth 0 (see the tiny test)
+        assert stats["pipelined_ticks"] == stats["ticks"] - 5 > 0, stats
+        # greedy placement that never finds drift enough to re-shard
+        placed, pstats = run(placement=PlacementConfig(
+            mode="greedy", drift_threshold=1.0, warmup_ticks=1))
+        assert pstats["pipelined_ticks"] == 0, pstats
+        assert pstats["placement_reshards"] == 0, pstats
+        assert sorted(placed) == sorted(out) == list(range(24))
+        for rid in out:
+            np.testing.assert_array_equal(placed[rid], out[rid])
+        paged, gstats = run(paging=PagingSpec(budget_bytes=None, depth=1))
+        assert gstats["pipelined_ticks"] == 0, gstats
+        assert gstats["paged_transfers"] > 0, gstats
+        assert sorted(paged) == list(range(24))
+        assert all(np.isfinite(x).all() for x in paged.values())
+        print("DEPTH-OK")
+    """)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, env=dict(os.environ, PYTHONPATH="src"),
+                       cwd=repo, timeout=1200)
+    assert "DEPTH-OK" in r.stdout, (r.stdout[-2000:], r.stderr[-3000:])
