@@ -31,7 +31,7 @@ from repro.configs.dit_moe_xl import config as xl_config, tiny
 from repro.configs.dit_moe_g import config as g_config
 from repro.core.schedules import DiceConfig
 from repro.data.synthetic import gaussian_mixture_latents, latent_batches
-from repro.launch.serve import modeled_step_latency
+from repro.metrics.latency_model import modeled_step_latency
 from repro.metrics.fid_proxy import fid_proxy, mse_vs_reference
 from repro.models.dit_moe import init_dit
 from repro.optim.adamw import adamw_init

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 from benchmarks import common
 from repro.core.schedules import DiceConfig
-from repro.launch.serve import modeled_step_latency
+from repro.metrics.latency_model import modeled_step_latency
 from repro.metrics.fid_proxy import fid_proxy, mse_vs_reference
 
 

@@ -14,7 +14,7 @@ from benchmarks import common
 from repro.configs.dit_moe_xl import config as xl_config
 from repro.core import plan as plan_lib
 from repro.core.schedules import DiceConfig
-from repro.launch.serve import modeled_step_latency
+from repro.metrics.latency_model import modeled_step_latency
 
 
 def buffer_bytes_per_method(cfg, method: str, *, local_batch: int,

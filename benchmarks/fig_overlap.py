@@ -35,7 +35,7 @@ def run(label: str = "fig_overlap"):
     from repro.configs.dit_moe_xl import config as xl_config
     from repro.core.schedules import DiceConfig
     from repro.launch.serve import SCHEDULES as SERVE_SCHEDULES
-    from repro.launch.serve import modeled_step_latency
+    from repro.metrics.latency_model import modeled_step_latency
     from repro.sampling.rectified_flow import rf_sample
 
     smoke = os.environ.get("BENCH_SMOKE") == "1"

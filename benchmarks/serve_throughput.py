@@ -36,8 +36,8 @@ from repro.configs.dit_moe_xl import tiny
 from repro.core import placement as placement_lib
 from repro.core.schedules import DiceConfig
 from repro.launch.serve import (DiceServer, Request, SCHEDULES,
-                                modeled_step_latency, serve_continuous,
-                                serve_queue, write_metrics)
+                                serve_continuous, serve_queue, write_metrics)
+from repro.metrics.latency_model import modeled_step_latency
 from repro.models.dit_moe import init_dit
 from repro.obs import MetricsRegistry, ObsConfig
 from repro.resilience import faults as fault_lib
@@ -124,7 +124,6 @@ def run(*, schedule: str = "dice", requests: int = 32, max_batch: int = 8,
         lanes = max(1, dp) * max(1, ep)
         max_batch = max(max_batch, lanes)
         max_batch -= max_batch % lanes
-    dcfg = SCHEDULES[schedule]()
     params = skewed_params(cfg, skew, seed=0)
     pspec = None
     if paging == "on":
@@ -132,10 +131,11 @@ def run(*, schedule: str = "dice", requests: int = 32, max_batch: int = 8,
         pspec = PagingSpec(
             budget_bytes=None if expert_hbm_budget < 0 else expert_hbm_budget,
             depth=paging_depth)
+    dcfg = dataclasses.replace(SCHEDULES[schedule](),
+                               compress=CompressConfig(codec=codec),
+                               overlap=overlap, paging=pspec)
     obs_on = bool(obs or trace_out or metrics_out)
     server = DiceServer(cfg, dcfg, params=params, mesh=mesh,
-                        compress=CompressConfig(codec=codec),
-                        overlap=overlap, paging=pspec,
                         obs=ObsConfig(enabled=obs_on))
     reqs = [Request(class_id=i % cfg.num_classes, rid=i)
             for i in range(requests)]
@@ -164,6 +164,18 @@ def run(*, schedule: str = "dice", requests: int = 32, max_batch: int = 8,
     # placed run over the SAME request trace measures what the layout
     # actually saves on the wire.  Parity gates against a mesh-less
     # identity baseline of the same trace.
+    # modeled latency of the REQUESTED deployment (dcfg as asked for, its
+    # codec and a2a engine included, not server.dcfg as this mesh runs
+    # it) on the mesh's ep size, or on the paper's 8 devices mesh-less
+    n_model = 8
+    if mesh is not None:
+        n_model = mesh.shape["ep"] if "ep" in mesh.axis_names else 1
+
+    def modeled(d):
+        return modeled_step_latency(cfg, d, n_dev=n_model,
+                                    local_batch=max(1, max_batch // n_model))
+
+    lat = modeled(dcfg)
     place_res = {}
     if placement == "greedy" and ep > 1:
         shares = np.asarray(cstats["routing_shares"])
@@ -176,7 +188,7 @@ def run(*, schedule: str = "dice", requests: int = 32, max_batch: int = 8,
                                           num_steps=num_steps,
                                           arrival_steps=arrivals,
                                           key=jax.random.PRNGKey(seed))
-        server_1d = DiceServer(cfg, server.dcfg, params=params, n_dev=ep)
+        server_1d = DiceServer(cfg, server.dcfg, params=params)
         out_1d, _ = serve_continuous(server_1d, reqs, max_batch=max_batch,
                                      num_steps=num_steps,
                                      arrival_steps=arrivals,
@@ -196,16 +208,12 @@ def run(*, schedule: str = "dice", requests: int = 32, max_batch: int = 8,
             "placement_jit_cache_size": pstats["jit_cache_size"],
             "placement_num_plan_variants": pstats["num_plan_variants"],
             # modeled ring-step latency with vs without the placement
-            "modeled_step_ring_s_identity": cstats["modeled_step_ring_s"],
-            "modeled_step_ring_s_placed": pstats["modeled_step_ring_s"],
+            "modeled_step_ring_s_identity": lat["t_step_ring_s"],
+            "modeled_step_ring_s_placed": modeled(dataclasses.replace(
+                dcfg, placements=pls))["t_step_ring_s"],
         }
 
-    # server.dcfg, not the local dcfg: DiceServer threads the CompressConfig
-    # into its schedule config, and the codec-aware light_scale of the
-    # latency model must see it
-    t_step = modeled_step_latency(cfg, server.dcfg, n_dev=server.n_dev,
-                                  local_batch=max(1, max_batch
-                                                  // server.n_dev))["t_step_s"]
+    t_step = lat["t_step_s"]
     fifo_slot_steps = fifo_batches * max_batch * num_steps
     res = {
         "schedule": schedule,
@@ -226,7 +234,7 @@ def run(*, schedule: str = "dice", requests: int = 32, max_batch: int = 8,
         "num_plan_variants": cstats["num_plan_variants"],
         "jit_cache_size": cstats["jit_cache_size"],
         "fifo_dispatch_bytes_total": fstats["dispatch_bytes_total"],
-        "fifo_a2a_bytes_per_layer": fstats["a2a_bytes_per_layer"],
+        "fifo_a2a_bytes_per_layer": lat["a2a_bytes_layer"],
         "fifo_buffer_bytes": fstats["buffer_bytes"],
         # wire vs raw payload (Sec. 11): ratio > 1 iff a codec is active
         "codec": codec,
@@ -240,9 +248,9 @@ def run(*, schedule: str = "dice", requests: int = 32, max_batch: int = 8,
         "overlap": overlap,
         "cont_ring_hops": cstats["ring_hops"],
         "cont_hop_bytes_total": cstats["hop_bytes_total"],
-        "modeled_overlap_efficiency": cstats["modeled_overlap_efficiency"],
-        "modeled_step_blocking_s": cstats["modeled_step_blocking_s"],
-        "modeled_step_ring_s": cstats["modeled_step_ring_s"],
+        "modeled_overlap_efficiency": lat["overlap_efficiency"],
+        "modeled_step_blocking_s": lat["t_step_blocking_s"],
+        "modeled_step_ring_s": lat["t_step_ring_s"],
         # routing skew + placement (DESIGN.md Sec. 13)
         "skew": skew,
         "placement": placement,
@@ -348,9 +356,10 @@ def run_chaos(*, faults: str, requests: int = 8, max_batch: int = 4,
         pspec = PagingSpec(budget_bytes=None, depth=1)
     for name in SCHEDULES:
         def _run(resilience, obs=False):
-            srv = DiceServer(cfg, SCHEDULES[name](), params=params,
-                             mesh=mesh, resilience=resilience,
-                             paging=pspec, obs=ObsConfig(enabled=obs))
+            dcfg = dataclasses.replace(SCHEDULES[name](), paging=pspec,
+                                       resilience=resilience)
+            srv = DiceServer(cfg, dcfg, params=params, mesh=mesh,
+                             obs=ObsConfig(enabled=obs))
             o, s = serve_continuous(srv, reqs, max_batch=max_batch,
                                     num_steps=num_steps,
                                     arrival_steps=arrivals,

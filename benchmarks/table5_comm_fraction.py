@@ -25,7 +25,7 @@ class HwPoint:
     link_bw: float     # bytes/s effective per device
 
 
-from repro.launch.serve import PAPER_HW, TPU_HW, layer_compute_flops
+from repro.metrics.latency_model import PAPER_HW, TPU_HW, layer_compute_flops
 
 HWS = [
     HwPoint("rtx4090_pcie", PAPER_HW["flops"], PAPER_HW["link_bw"]),

@@ -20,6 +20,7 @@ every check held.  Times and rates printed here are measured on the chip
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import sys
 import time
@@ -131,8 +132,10 @@ def serve(cfg, params, schedule, failures, *, mesh=None, overlap=None,
     from repro.launch.serve import (SCHEDULES, DiceServer, Request,
                                     request_noise, serve_continuous)
 
-    server = DiceServer(cfg, SCHEDULES[schedule](), params=params,
-                        mesh=mesh, overlap=overlap)
+    dcfg = SCHEDULES[schedule]()
+    if overlap is not None:
+        dcfg = dataclasses.replace(dcfg, overlap=overlap)
+    server = DiceServer(cfg, dcfg, params=params, mesh=mesh)
     reqs = [Request(class_id=(125 * i) % cfg.num_classes, rid=i)
             for i in range(NUM_REQUESTS)]
     key = jax.random.PRNGKey(SEED)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from repro.configs.dit_moe_xl import tiny
 from repro.launch.serve import SCHEDULES, DiceServer, Request
 from repro.metrics.fid_proxy import mse_vs_reference
+from repro.metrics.latency_model import modeled_step_latency
 from repro.models.dit_moe import init_dit
 
 
@@ -40,13 +41,17 @@ def main():
     print(f"{'schedule':28s} {'mse_vs_sync':>12s} {'modeled_step_ms':>16s} "
           f"{'buffer_bytes':>13s}")
     for name in SCHEDULES:
-        server = DiceServer(cfg, SCHEDULES[name](), params=params)
+        dcfg = SCHEDULES[name]()
+        server = DiceServer(cfg, dcfg, params=params)
         samples, stats = server.generate(reqs, num_steps=args.steps)
+        # the paper's 8-device deployment, each device a share of the batch
+        lat = modeled_step_latency(cfg, dcfg,
+                                   local_batch=max(1, len(reqs) // 8))
         mse = 0.0 if ref is None else mse_vs_reference(samples, ref)
         if ref is None:
             ref = samples
         print(f"{name:28s} {mse:12.6f} "
-              f"{stats['modeled_step_s_tpu8']*1e3:16.3f} "
+              f"{lat['t_step_s']*1e3:16.3f} "
               f"{stats['buffer_bytes']:13,.0f}")
 
 

@@ -421,11 +421,27 @@ def strip_expert_params(params):
     return out
 
 
+def split_params(params, expert_pool: Optional[ExpertPool], n_ep: int):
+    """The paging split on an ``n_ep``-way ep axis: ``(device-resident
+    params, pool)``.  The pool is built from the routed-expert stacks of
+    ``params`` unless one is given, and must be sharded for ``n_ep``."""
+    if expert_pool is None and has_expert_leaves(params):
+        expert_pool = pool_from_params(params, n_dev=n_ep)
+    if expert_pool is None:
+        raise ValueError("paging is configured but params carry no expert "
+                         "leaves and no expert_pool was provided")
+    if expert_pool.n_dev != n_ep:
+        raise ValueError(
+            f"expert pool is sharded for {expert_pool.n_dev} devices but "
+            f"the serving mesh has a {n_ep}-way ep axis")
+    return strip_expert_params(params), expert_pool
+
+
 def _leaf_name(path) -> str:
     return str(getattr(path[-1], "key", getattr(path[-1], "idx", "")))
 
 
-def load_pooled_checkpoint(path: str, like, *, n_dev: int):
+def load_pooled_checkpoint(path: str, like, n_dev: int):
     """Streamed checkpoint restore straight into the paging split.
 
     Walks the checkpoint's leaves one at a time (validated against
@@ -461,7 +477,8 @@ def load_pooled_checkpoint(path: str, like, *, n_dev: int):
 
 
 # ---------------------------------------------------------------------------
-# config plumbing (mirrors plan.overlap_of / normalize_overlap)
+# config plumbing (mirrors plan.overlap_of; plan.resolve strips paging
+# off an n>1 ep axis)
 # ---------------------------------------------------------------------------
 def paging_of(dcfg) -> Optional[PagingSpec]:
     """The planned paging spec of ``dcfg``, or None.  ``getattr`` so
@@ -482,20 +499,3 @@ def resolve_budget(dcfg, pool: ExpertPool):
     return dataclasses.replace(
         dcfg, paging=dataclasses.replace(
             spec, budget_bytes=pool.min_budget_bytes(spec.depth)))
-
-
-def normalize_paging(dcfg, n_dev: int):
-    """Strip ``dcfg.paging`` when no multi-device ep axis backs the run.
-
-    Paging is a property of an n>1 ep mesh axis: on one device (or
-    mesh-less) every expert is local, the params keep their expert
-    stacks, and a plan that still carried paging would key extra jit
-    entries for a bit-identical computation.  Samplers and the serving
-    engine call this with the mesh's ep size before compiling plans —
-    exactly like ``normalize_overlap`` / ``normalize_placement`` — so
-    mesh-less plan variants and outputs stay bit-identical to
-    fully-resident configs.
-    """
-    if n_dev > 1 or paging_of(dcfg) is None:
-        return dcfg
-    return dataclasses.replace(dcfg, paging=None)

@@ -34,9 +34,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.compress.codecs import CodecSpec
 from repro.core import conditional
-from repro.core.paging import PagingSpec, normalize_paging, paging_of
+from repro.core.paging import PagingSpec, paging_of
 from repro.core.placement import Placement
 from repro.core.selective import sync_layer_mask
+from repro.resilience.faults import normalize_resilience
 
 
 # ---------------------------------------------------------------------------
@@ -82,10 +83,10 @@ class LayerAction:
         against the expert FFN instead of two monolithic blocking
         all-to-alls.  Same wire volume and identical per-row math — a
         pure execution-shape property, planned per step so the (hashable)
-        flag keys the jit cache like every other field.  Entry points
-        normalize it away when no ep mesh (or a 1-device axis) backs the
-        run (:func:`normalize_overlap`), so single-device plan variants
-        and outputs stay bit-identical to blocking.
+        flag keys the jit cache like every other field.  :func:`resolve`
+        strips it when no ep mesh (or a 1-device axis) backs the run, so
+        single-device plan variants and outputs stay bit-identical to
+        blocking.
     placement
         this layer's expert layout (DESIGN.md Sec. 13): the dispatch
         buffer's expert order, the replica set served locally off the
@@ -433,22 +434,6 @@ def overlap_of(dcfg) -> bool:
     return getattr(dcfg, "overlap", "blocking") == "ring"
 
 
-def normalize_overlap(dcfg, n_dev: int):
-    """Strip ``overlap="ring"`` when no multi-device ep axis backs the run.
-
-    The ring engine is an execution-shape property of an n>1 mesh axis: on
-    one device there is no wire, hop 0 IS the whole layer, and a plan that
-    still carried ``overlap=True`` would key a second jit entry for a
-    bit-identical computation.  Samplers and the serving engine call this
-    with the mesh's ep size (1 when mesh-less) before compiling plans, so
-    single-device plan variants — and therefore outputs — stay
-    bit-identical to blocking configs.
-    """
-    if n_dev > 1 or not overlap_of(dcfg):
-        return dcfg
-    return dataclasses.replace(dcfg, overlap="blocking")
-
-
 def placements_of(dcfg) -> Optional[Tuple[Placement, ...]]:
     """The per-layer expert placements of ``dcfg``, or None.  ``getattr``
     so pre-placement config objects (and test doubles) keep planning
@@ -456,22 +441,45 @@ def placements_of(dcfg) -> Optional[Tuple[Placement, ...]]:
     return getattr(dcfg, "placements", None)
 
 
-def normalize_placement(dcfg, n_dev: int):
-    """Strip ``dcfg.placements`` when no multi-device ep axis backs the run.
+def resolve(dcfg, n_ep: int):
+    """``dcfg`` as it runs on an ``n_ep``-way ep axis (1 when mesh-less).
 
-    A placement permutes the expert stacks into device-major wire order
-    and serves replicas locally — properties of an n>1 mesh axis.  On one
-    device every expert is already local, the caller's params are in the
-    ORIGINAL layout (``placed_params`` only runs on the mesh path), and a
-    plan that still carried placements would both mis-index the experts
-    and key extra jit entries.  Samplers and the serving engine call this
-    with the mesh's ep size (1 when mesh-less) before compiling plans —
-    exactly like :func:`normalize_overlap` — so single-device plan
-    variants and outputs stay bit-identical to pre-placement configs.
+    The one place a requested config meets the mesh that serves it;
+    ``DiceServer`` calls it once at construction and ``rf_sample`` once
+    per call (it also takes raw configs), and every plan and step is then
+    built from the result:
+
+    * a ``CompressConfig(codec="none")`` IS the lossless wire: no codec;
+    * an inert ``ResilienceConfig`` is no resilience
+      (:func:`repro.resilience.faults.normalize_resilience`), so "off"
+      keeps every traced graph byte-identical;
+    * without an n>1 ep axis, ``overlap="ring"``, ``placements`` and
+      ``paging`` strip.  Each is a property of a multi-device axis: on
+      one device the ring has no wire to hide (hop 0 is the whole
+      layer), every expert is already local in the caller's unpermuted
+      params, and nothing needs paging in.  A plan that still carried
+      them would key extra jit entries for the same computation (and a
+      placement would mis-index the experts), so single-device plans and
+      samples stay bit-identical to the plain config.
+
+    Idempotent, and returns ``dcfg`` itself when nothing changes.  Reads
+    each field by ``getattr``, so configs without one plan unchanged.
     """
-    if n_dev > 1 or placements_of(dcfg) is None:
-        return dcfg
-    return dataclasses.replace(dcfg, placements=None)
+    changes = {}
+    compress = getattr(dcfg, "compress", None)
+    if compress is not None and compress.codec == "none":
+        changes["compress"] = None
+    res = getattr(dcfg, "resilience", None)
+    if normalize_resilience(res) is not res:
+        changes["resilience"] = normalize_resilience(res)
+    if n_ep <= 1:
+        if overlap_of(dcfg):
+            changes["overlap"] = "blocking"
+        if placements_of(dcfg) is not None:
+            changes["placements"] = None
+        if paging_of(dcfg) is not None:
+            changes["paging"] = None
+    return dataclasses.replace(dcfg, **changes) if changes else dcfg
 
 
 def normalize_hop_schedule(hop_schedule, n_dev: int):
@@ -482,7 +490,7 @@ def normalize_hop_schedule(hop_schedule, n_dev: int):
     what any device receives, so numerics are identical by construction.
     Normalizing the natural order (and anything on a <=1-device axis)
     to ``None`` keeps mesh-less and oblivious-ring runs on the exact
-    historical code path, mirroring :func:`normalize_overlap`.
+    historical code path, as :func:`resolve` does for the config.
     """
     if hop_schedule is None or n_dev <= 1:
         return None
@@ -498,7 +506,7 @@ def normalize_hop_schedule(hop_schedule, n_dev: int):
 def placement_wire_scale(dcfg) -> float:
     """Mean planned capacity scale over layers (1.0 without placements) —
     the factor by which placement shrinks every capacity-sized wire
-    payload; the serving latency model scales its a2a volume by it."""
+    payload; the latency model scales its a2a volume by it."""
     placements = placements_of(dcfg)
     if not placements:
         return 1.0

@@ -70,26 +70,25 @@ class DiceConfig:
     # -- execution level: how the dispatch/combine collectives lower ----------
     # (DESIGN.md Sec. 12) "blocking" = two monolithic all-to-alls around the
     # expert FFN; "ring" = (n-1)-hop chunked ppermute pipeline that hides
-    # each hop's wire time behind the expert GEMMs.  Normalized back to
-    # "blocking" by the entry points when no n>1 ep mesh backs the run.
+    # each hop's wire time behind the expert GEMMs.  Resolved back to
+    # "blocking" when no n>1 ep mesh backs the run (plan.resolve).
     overlap: str = "blocking"
     # -- expert level: affinity-aware placement + hot-expert replication ------
     # (DESIGN.md Sec. 13) one Placement per MoE layer, stamped onto every
     # LayerAction by the plan compiler; the caller re-lays-out the expert
     # params to match (repro.core.placement.placed_params).  None — or a
     # tuple of identity placements, which normalize away — is the
-    # pre-placement layout.  Normalized to None by the entry points when
-    # no n>1 ep mesh backs the run (plan.normalize_placement).
+    # pre-placement layout.  Resolved to None when no n>1 ep mesh backs
+    # the run (plan.resolve).
     placements: Optional[Tuple[Optional[Placement], ...]] = None
     # -- memory level: expert paging + async prefetch --------------------------
     # (DESIGN.md Sec. 15) with a PagingSpec the routed-expert stacks live in
     # a host-RAM ExpertPool and each device keeps only a (depth+1)-layer
     # window of shards resident, fetched one layer ahead inside the traced
     # step.  Stamped onto every LayerAction by the plan compiler (prefetch /
-    # resident fields); mutually exclusive with ``placements``.  Normalized
-    # to None by the entry points when no n>1 ep mesh backs the run
-    # (repro.core.paging.normalize_paging), so mesh-less runs stay
-    # bit-identical to fully-resident configs.
+    # resident fields); mutually exclusive with ``placements``.  Resolved
+    # to None when no n>1 ep mesh backs the run (plan.resolve), so
+    # mesh-less runs stay bit-identical to fully-resident configs.
     paging: Optional[PagingSpec] = None
     # -- resilience level: fault injection + degraded modes --------------------
     # (DESIGN.md Sec. 17) seeded deterministic fault injection plus the
@@ -98,7 +97,8 @@ class DiceConfig:
     # like compress/paging — the planner ignores it, so plans, jit
     # signatures, and the plan-variant count are untouched; None (the
     # default) keeps graphs byte-identical to the pre-resilience stack.
-    # Normalized by repro.resilience.faults.normalize_resilience.
+    # Inert configs resolve to None (plan.resolve, through
+    # repro.resilience.faults.normalize_resilience).
     resilience: Optional[ResilienceConfig] = None
 
     def __post_init__(self):

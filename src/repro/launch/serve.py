@@ -7,10 +7,9 @@ modes: rigid FIFO batches (:func:`serve_queue`) and continuous batching
 and completes independently and freed slots are recycled mid-flight with
 slot-level staleness-state resets.  Besides the samples it reports the
 quantities behind the paper's claims: per-step all-to-all payload,
-persistent staleness-buffer bytes, measured wall seconds (taken after
-``block_until_ready``, on whatever backend ran), and a step latency
-MODELED from roofline terms for the paper's 8-GPU setup (the
-``modeled_*`` keys; never a measurement).
+persistent staleness-buffer bytes and measured wall seconds (taken after
+``block_until_ready``, on whatever backend ran).  Modeled latencies are
+the paper-table scripts' business (:mod:`repro.metrics.latency_model`).
 
   PYTHONPATH=src python -m repro.launch.serve --schedule dice \
       --requests 16 --steps 20
@@ -31,7 +30,7 @@ import numpy as np
 
 from repro.checkpoint import load_checkpoint
 from repro.common.compile_cache import enable_compile_cache
-from repro.common.config import HW, ModelConfig
+from repro.common.config import ModelConfig
 from repro.compress.codecs import CODEC_KINDS, CompressConfig
 from repro.configs.dit_moe_xl import config as xl_config, tiny
 from repro.core import conditional
@@ -41,7 +40,6 @@ from repro.core import placement as placement_lib
 from repro.core import plan as plan_lib
 from repro.core import staleness as stale_lib
 from repro.core.schedules import DiceConfig
-from repro.core.conditional import comm_volume_fraction
 from repro.models.dit_moe import init_dit
 from repro.obs import MetricsRegistry, ObsConfig, StepTracer
 from repro.obs import telemetry as obs_fields
@@ -67,217 +65,6 @@ SCHEDULES = {
 
 
 # ---------------------------------------------------------------------------
-# modeled step latency on the target hardware (per DESIGN.md Sec. 2:
-# wall-clock speedups cannot be measured on CPU; the model uses the roofline
-# terms per MoE layer and the schedule's overlap structure)
-# ---------------------------------------------------------------------------
-# The paper's setup: 8x RTX 4090 over PCIe.  Effective (not peak) constants,
-# calibrated against the paper's own Table 5 measurement (all-to-all is
-# 75.6-79.2% of sync-EP step time on DiT-MoE-XL at batch 4-32):
-#   flops   = 82.6 TF dense bf16 x ~45% achieved utilisation,
-#   link_bw = ~0.9 GB/s effective per-GPU all-to-all bandwidth (8 GPUs
-#             contending through host PCIe root complexes).
-PAPER_HW = {"flops": 37e12, "link_bw": 0.9e9}
-TPU_HW = {"flops": HW.peak_flops_bf16, "link_bw": HW.ici_bw * 4}
-
-
-def layer_compute_flops(cfg: ModelConfig, tokens: int) -> float:
-    """Per-MoE-layer forward flops (attention + routed + shared experts).
-
-    Attention: QKV + output projections are four d x d matmuls per token
-    (8*T*d^2 multiply-adds-counted-as-2), and the QK^T + AV score terms
-    are 4*T^2*d.  The routed/shared expert FFNs are three d x d_ff matmuls
-    per dispatched token (gated SwiGLU).
-    """
-    d = cfg.d_model
-    attn_flops = 8 * tokens * d * d + 4 * tokens ** 2 * d
-    moe_flops = 6 * tokens * d * cfg.expert_d_ff * (
-        cfg.experts_per_token + cfg.num_shared_experts)
-    return attn_flops + moe_flops
-
-
-def hop_wire_times(t_comm: float, n_dev: int, sched, *,
-                   devices_per_host: int, link_bw: float,
-                   inter_host_bw: float) -> List[float]:
-    """Per-hop wire seconds of a chunked ring a2a on a two-tier fabric.
-
-    ``t_comm / (n-1)`` is the homogeneous per-hop chunk time.  A shift-h
-    hop pushes ``hop_crossings(h, n, H)`` of each host's H chunks through
-    the single inter-host trunk (they contend; intra-host links run in
-    parallel), so its time is the slower of the trunk serialisation and
-    one intra-host chunk transfer (DESIGN.md §14).
-    """
-    base = t_comm / max(1, n_dev - 1)
-    out = []
-    for h in sched:
-        c = overlap_lib.hop_crossings(h, n_dev, devices_per_host)
-        out.append(max(base, c * base * (link_bw / inter_host_bw)))
-    return out
-
-
-def _ring_pipeline_bound(chunk_comp: float, wire_times) -> float:
-    """Flow-shop recurrence of the ring engine over explicit per-hop wire
-    times: the local chunk's FFN runs behind hop 1's wire, then each
-    arriving chunk computes as soon as BOTH its data has landed and the
-    previous chunk's FFN is done.  With equal wire times this reduces
-    exactly to the closed form ``t_local + (n-1) * max(w, c)``."""
-    done = chunk_comp                 # local chunk, free behind hop 1
-    wire = 0.0
-    for w in wire_times:
-        wire += w
-        done = max(done, wire) + chunk_comp
-    return done
-
-
-def modeled_step_latency(cfg: ModelConfig, dcfg: DiceConfig, *,
-                         local_batch: int, n_dev: int = 8,
-                         hw: Optional[dict] = None,
-                         devices_per_host: int = 0,
-                         inter_host_bw: Optional[float] = None) -> dict:
-    """Seconds per diffusion step on n_dev devices.
-
-    Defaults to the paper's hardware point (8x RTX 4090 over PCIe, where
-    all-to-all is 60-80% of step time and DICE's overlap pays 1.2-1.26x);
-    pass hw=TPU_HW for the v5e target, where ICI bandwidth shrinks the
-    communication share and with it the achievable overlap gain.
-
-    Execution-faithful since the ring engine (DESIGN.md Sec. 12): a
-    ``dcfg.overlap == "blocking"`` layer is modeled SERIAL
-    (``t_comp + t_comm`` — the two monolithic all-to-alls block, whatever
-    the staleness schedule does about when results are consumed), while
-    ``"ring"`` uses the per-hop pipeline bound
-
-        t_local + Σ_h max(t_hop_comm(h), t_hop_comp)
-
-    — the flow-shop recurrence over the hop schedule, which on a
-    homogeneous fabric reduces exactly to the closed form
-    ``t_local + (n-1) * max(t_hop_comm, t_hop_comp)``.  The returned dict
-    always carries BOTH bounds (``t_step_blocking_s`` /
-    ``t_step_ring_s``) plus ``overlap_efficiency`` — the fraction of the
-    step's communication time the selected mode hides.
-
-    ``devices_per_host`` H with ``inter_host_bw`` < link_bw models the
-    two-tier fabric of DESIGN.md §14: hops whose shift crosses host
-    boundaries serialise their crossing chunks through the inter-host
-    trunk.  The ring bound then follows the TOPOLOGY-AWARE hop order
-    (``repro.core.overlap.ring_hop_schedule`` — cheap intra-host hops
-    first, the flow-shop optimum), and ``t_step_ring_oblivious_s``
-    additionally reports the natural-order schedule for comparison.
-    """
-    hw = hw or PAPER_HW
-    hetero = (0 < devices_per_host < n_dev
-              and inter_host_bw is not None
-              and inter_host_bw < hw["link_bw"]
-              and n_dev % max(1, devices_per_host) == 0)
-    # steady-state StepPlan: the single source of truth for which layers
-    # block (replaces the per-schedule if/elif that used to live here)
-    steady = plan_lib.steady_state_plan_for(dcfg, cfg.num_layers,
-                                            experts_per_token=cfg.experts_per_token)
-    tokens = local_batch * cfg.patch_tokens
-    d = cfg.d_model
-    t_comp = layer_compute_flops(cfg, tokens) / hw["flops"]
-    # per-layer all-to-all: dispatch + combine of the capacity buffer
-    cap_tokens = tokens * cfg.experts_per_token * cfg.capacity_factor
-    a2a_full = 2 * cap_tokens * d * 2 * (n_dev - 1) / n_dev
-    # affinity-aware placement (Sec. 13): hot replicated experts serve
-    # their tokens locally and the dispatch capacity scales down with
-    # them, shrinking every capacity-sized wire payload by the planned
-    # mean per-layer capacity scale (1.0 without placements)
-    a2a_full *= plan_lib.placement_wire_scale(dcfg)
-    a2a_async = a2a_full
-    # wire codec (Sec. 11): light-step payloads shrink by the codec's
-    # ratio at the 2-byte (bf16/fp16) wire dtype the model counts in
-    light_scale = 1.0
-    cspec = plan_lib.codec_spec_of(dcfg)
-    if cspec is not None and plan_lib.schedule_name(dcfg.schedule) in (
-            "displaced", "interweaved", "dice"):
-        light_scale = cspec.wire_ratio(d, itemsize=2)
-    if dcfg.cond_comm:
-        # conditional communication gates ASYNC layers only; synchronized
-        # layers transmit everything fresh (that is their purpose)
-        a2a_async = a2a_full * comm_volume_fraction(
-            cfg.experts_per_token, dcfg.cond_stride, dcfg.cond_policy,
-            light_scale=light_scale)
-    elif light_scale < 1.0 and dcfg.cond_stride > 1:
-        # codec without conditional communication: every rank still moves,
-        # but non-refresh steps move it compressed
-        a2a_async = a2a_full * (
-            1 + (dcfg.cond_stride - 1) * light_scale) / dcfg.cond_stride
-    t_comm_full = a2a_full / hw["link_bw"]
-    t_comm_async = a2a_async / hw["link_bw"]
-
-    if plan_lib.schedule_name(dcfg.schedule) == "staggered_batch":
-        # supplement Sec. 8: two half-batches -> each expert GEMM runs at
-        # lower utilization (saturating efficiency curve)
-        def eff(b):
-            return b / (b + 4)
-        t_comp = t_comp * eff(local_batch) / eff(max(1, local_batch // 2))
-
-    sync_frac = steady.num_sync_layers / max(1, steady.num_layers)
-
-    aware_sched = oblivious_sched = tuple(range(1, n_dev))
-    if hetero:
-        aware_sched = overlap_lib.ring_hop_schedule(
-            n_dev, devices_per_host=devices_per_host)
-
-    def _wire(tm, sched):
-        return hop_wire_times(tm, n_dev, sched,
-                              devices_per_host=devices_per_host,
-                              link_bw=hw["link_bw"],
-                              inter_host_bw=inter_host_bw)
-
-    def ring_bound(tc: float, tm: float, sched=None) -> float:
-        """Per-hop pipeline bound of the ring engine: the local chunk's
-        FFN hides behind hop 1's wire, then each of the n-1 hops costs
-        the slower of one chunk transfer and one chunk compute.  On a
-        homogeneous fabric the exact closed form; on a two-tier one the
-        flow-shop recurrence over the given hop order."""
-        if n_dev <= 1:
-            return tc + tm
-        t_local = tc / n_dev
-        if not hetero:
-            return t_local + (n_dev - 1) * max(tm / (n_dev - 1), tc / n_dev)
-        return _ring_pipeline_bound(
-            t_local, _wire(tm, aware_sched if sched is None else sched))
-
-    def _comm(tm: float) -> float:
-        """Wire time of one monolithic a2a: Σ per-hop times (the blocking
-        collective moves every hop's payload with nothing to hide behind;
-        order-invariant).  Homogeneous: exactly ``tm``."""
-        return sum(_wire(tm, oblivious_sched)) if hetero else tm
-
-    def step_of(t_sync: float, t_async: float) -> float:
-        return cfg.num_layers * (sync_frac * t_sync
-                                 + (1 - sync_frac) * t_async)
-
-    # blocking: the monolithic all-to-alls serialize against compute —
-    # synchronized AND staleness layers alike (staleness only moves when
-    # results are consumed, never when the collectives block)
-    t_blocking = step_of(t_comp + _comm(t_comm_full),
-                         t_comp + _comm(t_comm_async))
-    t_ring = step_of(ring_bound(t_comp, t_comm_full),
-                     ring_bound(t_comp, t_comm_async))
-    t_ring_obl = (step_of(ring_bound(t_comp, t_comm_full, oblivious_sched),
-                          ring_bound(t_comp, t_comm_async, oblivious_sched))
-                  if hetero else t_ring)
-    t_step = t_ring if plan_lib.overlap_of(dcfg) else t_blocking
-    t_comm_step = cfg.num_layers * (sync_frac * _comm(t_comm_full)
-                                    + (1 - sync_frac) * _comm(t_comm_async))
-    efficiency = ((t_blocking - t_step) / t_comm_step
-                  if t_comm_step > 0 else 0.0)
-    return {"t_step_s": t_step,
-            "t_step_blocking_s": t_blocking,
-            "t_step_ring_s": t_ring,
-            "t_step_ring_oblivious_s": t_ring_obl,
-            "hop_schedule": aware_sched if hetero else None,
-            "overlap_efficiency": max(0.0, min(1.0, efficiency)),
-            "t_comp_layer": t_comp,
-            "t_comm_layer": t_comm_async, "sync_frac": sync_frac,
-            "a2a_bytes_layer": sync_frac * a2a_full
-            + (1 - sync_frac) * a2a_async}
-
-
-# ---------------------------------------------------------------------------
 # metrics publication (DESIGN.md Sec. 16): the registry is the single
 # source of truth; the summary dicts the serving loops return are VIEWS
 # computed from it.  The metric TYPE encodes the aggregation rule that
@@ -290,15 +77,6 @@ def _publish_batch(reg: MetricsRegistry, stats: dict, lab: dict) -> None:
     """Publish one ``DiceServer.generate`` summary into a registry."""
     reg.counter("dice_batches_total", "generate() batches executed",
                 lab).inc()
-    reg.histogram("dice_modeled_step_seconds",
-                  "modeled per-step latency on the target deployment",
-                  lab).observe(stats["modeled_step_s_tpu8"])
-    reg.counter("dice_modeled_seconds_total",
-                "modeled run seconds on the target deployment",
-                lab).inc(stats["modeled_total_s_tpu8"])
-    reg.gauge("dice_a2a_bytes_per_layer",
-              "modeled per-MoE-layer all-to-all payload",
-              lab).set_max(float(stats["a2a_bytes_per_layer"]))
     reg.gauge("dice_buffer_bytes", "persistent staleness-buffer footprint",
               lab).set_max(int(stats["buffer_bytes"]))
     reg.counter("dice_dispatch_bytes_total", "dispatch payload moved",
@@ -309,9 +87,6 @@ def _publish_batch(reg: MetricsRegistry, stats: dict, lab: dict) -> None:
               lab).set_max(int(stats["ring_hops"]))
     reg.counter("dice_hop_bytes_total", "per-device one-hop ring wire",
                 lab).inc(float(stats["hop_bytes_total"]))
-    reg.gauge("dice_overlap_efficiency",
-              "fraction of comm time the selected engine hides",
-              lab).set_max(float(stats["modeled_overlap_efficiency"]))
     reg.gauge("dice_plan_variants", "compiled StepPlan variants",
               lab).set_max(stats["num_plan_variants"])
     reg.gauge("dice_jit_cache_size", "jit cache entries of the step fn",
@@ -376,10 +151,6 @@ def _registry_view(reg: MetricsRegistry, lab: dict) -> dict:
     view = {
         "batches": int(reg.value("dice_batches_total", lab)),
         "padded": int(reg.value("dice_padded_requests_total", lab)),
-        "modeled_step_s_tpu8": reg.histogram("dice_modeled_step_seconds",
-                                             labels=lab).mean,
-        "modeled_total_s_tpu8": reg.value("dice_modeled_seconds_total", lab),
-        "a2a_bytes_per_layer": reg.value("dice_a2a_bytes_per_layer", lab),
         "buffer_bytes": int(reg.value("dice_buffer_bytes", lab)),
         "dispatch_bytes_total": reg.value("dice_dispatch_bytes_total", lab),
         # the dispatch payload IS the wire payload (codec-compressed)
@@ -387,8 +158,6 @@ def _registry_view(reg: MetricsRegistry, lab: dict) -> dict:
         "raw_bytes_total": reg.value("dice_raw_bytes_total", lab),
         "ring_hops": int(reg.value("dice_ring_hops", lab)),
         "hop_bytes_total": reg.value("dice_hop_bytes_total", lab),
-        "modeled_overlap_efficiency": reg.value("dice_overlap_efficiency",
-                                                lab),
         "num_plan_variants": int(reg.value("dice_plan_variants", lab)),
         "jit_cache_size": int(reg.value("dice_jit_cache_size", lab)),
     }
@@ -419,34 +188,32 @@ def write_metrics(registry: MetricsRegistry, path: str) -> None:
 # engine
 # ---------------------------------------------------------------------------
 class DiceServer:
-    """``n_dev`` is the ep fan-out of the serving mesh; it feeds both the
-    per-device local batch and the all-to-all fan-out of the latency model.
+    """One model served under one :class:`DiceConfig`.
+
+    The constructor resolves ``dcfg`` once against the serving mesh
+    (:func:`repro.core.plan.resolve` with the mesh's ep size, 1 with no
+    mesh; and the paging budget against the expert pool), so
+    ``server.dcfg`` is the config every plan and step of the server
+    runs.  Set the codec, the a2a engine, paging and resilience on the
+    ``DiceConfig`` itself.
 
     ``mesh`` (any hierarchical dp x ep x patch mesh from
     ``launch.mesh.make_mesh``, incl. the flat ``make_ep_mesh``) makes the
     server mesh-native: ``generate`` and :func:`serve_continuous` execute
     the real sharded dispatch/combine all-to-alls via the shard_map-
-    lowered step functions (DESIGN.md §10/§14), and ``n_dev`` defaults to
-    the mesh's ep size (1 on an ep-less mesh) so the latency model
-    describes the mesh actually running.  ``devices_per_host`` declares
-    the two-tier fabric: the ring engine then runs the topology-aware hop
-    schedule and the latency model prices inter-host hops at
-    ``inter_host_bw``."""
+    lowered step functions (DESIGN.md §10/§14).  ``devices_per_host``
+    declares the two-tier fabric: the ring engine then runs the
+    topology-aware hop schedule."""
 
     def __init__(self, cfg: ModelConfig, dcfg: DiceConfig, *,
-                 params=None, seed: int = 0, n_dev: Optional[int] = None,
+                 params=None, seed: int = 0,
                  mesh: Optional[jax.sharding.Mesh] = None,
                  ep_axis: str = "ep",
-                 compress: Optional[CompressConfig] = None,
-                 overlap: Optional[str] = None,
                  placement: Optional[placement_lib.PlacementConfig] = None,
-                 paging: Optional[paging_lib.PagingSpec] = None,
                  expert_pool: Optional[paging_lib.ExpertPool] = None,
                  devices_per_host: int = 0,
-                 inter_host_bw: Optional[float] = None,
                  obs: Optional[ObsConfig] = None,
-                 metrics: Optional[MetricsRegistry] = None,
-                 resilience: Optional[fault_lib.ResilienceConfig] = None):
+                 metrics: Optional[MetricsRegistry] = None):
         # observability plane (DESIGN.md Sec. 16): the registry is the
         # single source of truth the serving loops publish into (their
         # summary dicts are views of it); the tracer records host phases
@@ -455,50 +222,19 @@ class DiceServer:
         self.obs = obs if obs is not None else ObsConfig()
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.tracer = StepTracer() if self.obs.enabled else None
-        if compress is not None:
-            # thread the wire codec into the schedule config (Sec. 11);
-            # codec="none" normalizes to no compression so plans — and
-            # therefore outputs — stay bit-identical to an uncompressed
-            # server
-            dcfg = dataclasses.replace(
-                dcfg, compress=None if compress.codec == "none" else compress)
-        if overlap is not None:
-            # thread the a2a execution engine (Sec. 12) into the schedule
-            # config; the samplers normalize "ring" away when the server
-            # has no n>1 ep mesh, but the latency model keeps describing
-            # the REQUESTED engine on the target n_dev-device deployment
-            dcfg = dataclasses.replace(dcfg, overlap=overlap)
-        if paging is not None:
-            # thread the expert-paging spec (Sec. 15) into the schedule
-            # config; the samplers normalize it away on mesh-less / 1-dev
-            # runs, exactly like overlap and placement
-            dcfg = dataclasses.replace(dcfg, paging=paging)
-        resilience = fault_lib.normalize_resilience(resilience)
-        if resilience is not None:
-            # degradation-ladder policy (DESIGN.md Sec. 17): rides inside
-            # DiceConfig like compress/paging; the planner ignores it, so
-            # plans, variants, and jit-cache counts are untouched, and
-            # None keeps every traced graph byte-identical
-            dcfg = dataclasses.replace(dcfg, resilience=resilience)
         n_ep = (mesh.shape[ep_axis]
                 if mesh is not None and ep_axis in mesh.axis_names else 1)
-        if n_dev is None:
-            n_dev = n_ep if mesh is not None else 8
-        if n_dev < 1:
-            raise ValueError(f"n_dev must be >= 1, got {n_dev}")
+        dcfg = plan_lib.resolve(dcfg, n_ep)
         self.cfg = cfg
         self.dcfg = dcfg
-        self.n_dev = n_dev
         self.mesh = mesh
         self.ep_axis = ep_axis
-        self.devices_per_host = devices_per_host
-        self.inter_host_bw = inter_host_bw
         # topology-aware ring hop order (DESIGN.md §14): cheap intra-host
         # shifts first.  A pure permutation of the oblivious 1..n-1 order,
         # so numerics are identical; None (== natural order) off topology
         # or off the ring engine keeps the historical lowering.
         self.hop_schedule = None
-        if (plan_lib.overlap_of(dcfg) and n_ep > 1
+        if (plan_lib.overlap_of(dcfg)
                 and 0 < devices_per_host < n_ep
                 and n_ep % devices_per_host == 0):
             self.hop_schedule = plan_lib.normalize_hop_schedule(
@@ -518,23 +254,15 @@ class DiceServer:
         # along the plan's prefetch schedule.  The budget "auto" sentinel
         # resolves here — plans stamp the resolved spec.
         self.expert_pool = expert_pool
-        if paging_lib.paging_of(dcfg) is not None and n_ep > 1:
+        if paging_lib.paging_of(dcfg) is not None:
             if placement is not None and placement.mode == "greedy":
                 raise ValueError(
                     "expert paging and online affinity placement are "
                     "mutually exclusive: the pool serves per-layer shards "
                     "in canonical expert order (DESIGN.md Sec. 15)")
-            if (self.expert_pool is None
-                    and paging_lib.has_expert_leaves(self.params)):
-                self.expert_pool = paging_lib.pool_from_params(
-                    self.params, n_dev=n_ep)
-            if self.expert_pool is None:
-                raise ValueError(
-                    "paging is configured but params carry no expert "
-                    "leaves and no expert_pool was provided")
-            dcfg = paging_lib.resolve_budget(dcfg, self.expert_pool)
-            self.dcfg = dcfg
-            self.params = paging_lib.strip_expert_params(self.params)
+            self.params, self.expert_pool = paging_lib.split_params(
+                self.params, expert_pool, n_ep)
+            self.dcfg = paging_lib.resolve_budget(dcfg, self.expert_pool)
         if self.expert_pool is not None:
             # the pool's retry/fallback policy + seeded fetch faults
             # (DESIGN.md Sec. 17 rung 1) follow the server's config
@@ -573,25 +301,14 @@ class DiceServer:
                                    obs=self.obs, tracer=self.tracer)
         jax.block_until_ready(samples)
         wall = time.perf_counter() - t0
-        lat = modeled_step_latency(
-            self.cfg, self.dcfg, n_dev=self.n_dev,
-            local_batch=max(1, len(requests) // self.n_dev),
-            devices_per_host=self.devices_per_host,
-            inter_host_bw=self.inter_host_bw)
         result = {
             # measured on whatever backend ran the batch, compiles included
             "wall_s": wall,
-            "modeled_step_s_tpu8": lat["t_step_s"],
-            "modeled_total_s_tpu8": lat["t_step_s"] * num_steps,
-            "modeled_step_blocking_s": lat["t_step_blocking_s"],
-            "modeled_step_ring_s": lat["t_step_ring_s"],
-            "modeled_overlap_efficiency": lat["overlap_efficiency"],
             # ring execution stats (Sec. 12): collective-permutes per MoE
             # layer actually lowered (0 on the blocking path) and the
             # per-device one-hop wire total
             "ring_hops": max(stats["hops"], default=0),
             "hop_bytes_total": float(sum(stats["hop_bytes"])),
-            "a2a_bytes_per_layer": lat["a2a_bytes_layer"],
             "buffer_bytes": stats["buffer_bytes"][-1] if stats["buffer_bytes"]
             else 0,
             "dispatch_bytes_per_step": stats["dispatch_bytes"],
@@ -650,8 +367,8 @@ def serve_queue(server: "DiceServer", requests: List[Request], *,
     :class:`MetricsRegistry` (folded into ``server.metrics`` on return)
     and the returned summary is a *view* of it (DESIGN.md Sec. 16): the
     metric types encode the aggregation — flows are counters, per-batch
-    sizes are max-gauges, the modeled step time is a histogram mean —
-    so the sum/max/running-mean rules live in one place."""
+    sizes are max-gauges, request latencies are histograms — so the
+    sum/max/running-mean rules live in one place."""
     key = key if key is not None else jax.random.PRNGKey(0)
     out: dict = {}
     reg = MetricsRegistry()
@@ -782,8 +499,7 @@ def _admit_lanes_on(batch_sharding):
 def serve_continuous(server: "DiceServer", requests: List[Request], *,
                      max_batch: int = 8, num_steps: int = 10,
                      guidance: float = 1.5, key=None,
-                     arrival_steps: Optional[List[float]] = None,
-                     mesh: Optional[jax.sharding.Mesh] = None):
+                     arrival_steps: Optional[List[float]] = None):
     """Continuous-batching serving loop: slot-level admission + recycling.
 
     Unlike :func:`serve_queue` (rigid FIFO batches: a finished request
@@ -812,12 +528,12 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     benchmark: executed ticks, padded-slot step-executions, mean slot
     occupancy, and the aggregate byte/compile stats.
 
-    ``mesh`` (default: the server's mesh) runs every tick mesh-native:
-    slots shard over the ``"ep"`` axis, the admission program's outputs
-    are pinned to that layout (:func:`_admit_lanes_on`) so the jitted
-    step always sees one stable input layout, and the compile-count
-    guarantee (jit cache == plan-variant count) carries over to the
-    sharded path.
+    It runs ``server.dcfg`` as the server resolved it.  On the server's
+    mesh every tick runs mesh-native: slots shard over the ``"ep"``
+    axis, the admission program's outputs are pinned to that layout
+    (:func:`_admit_lanes_on`) so the jitted step always sees one stable
+    input layout, and the compile-count guarantee (jit cache ==
+    plan-variant count) carries over to the sharded path.
 
     One tick is kept in flight: tick t+1 is launched before the host
     reads back and completes tick t, so the host's per-tick work runs
@@ -862,26 +578,13 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     wait.  A request's ``admit`` and ``done`` instants share its
     ``rid``.
     """
-    cfg, dcfg = server.cfg, server.dcfg
-    mesh = mesh if mesh is not None else server.mesh
+    cfg, dcfg, mesh = server.cfg, server.dcfg, server.mesh
     ep_axis = server.ep_axis if mesh is not None else None
     if mesh is not None and "patch" in mesh.axis_names:
         raise ValueError(
             "continuous batching does not compose with a 'patch' mesh axis "
             "(slot surgery assumes batch-only sharding); use rigid batches "
             "via DiceServer.generate on patch meshes")
-    n_ep = (mesh.shape[ep_axis]
-            if mesh is not None and ep_axis in mesh.axis_names else 1)
-    # ring overlap needs an n>1 ep axis; normalize BEFORE planning so the
-    # compiled plans (and the jit-cache accounting below) match what the
-    # steps execute (DESIGN.md Sec. 12).  The latency model below keeps
-    # the un-normalized server.dcfg: it describes the target deployment.
-    dcfg = plan_lib.normalize_overlap(dcfg, n_ep)
-    # placement likewise is an n>1-mesh layout property (Sec. 13): the
-    # single-device server's params are unpermuted, so placements strip
-    dcfg = plan_lib.normalize_placement(dcfg, n_ep)
-    # and paging (Sec. 15): one device holds every expert locally
-    dcfg = paging_lib.normalize_paging(dcfg, n_ep)
     # resilience (DESIGN.md Sec. 17): the in-graph half (guards, seeded
     # corruption) rides in dcfg as a closure constant; the host-side
     # ladder — paging retry/fallback, watchdog demotion, quarantine,
@@ -891,17 +594,10 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     fplan = (fault_lib.FaultPlan(res.faults)
              if res is not None and res.faults is not None else None)
     ctrl = degrade_lib.DegradationController(res) if res is not None else None
+    # the server built the pool, for its mesh, wherever its config pages
     pool = (server.expert_pool
             if paging_lib.paging_of(dcfg) is not None else None)
-    if paging_lib.paging_of(dcfg) is not None:
-        if pool is None:
-            raise ValueError("paging is planned but the server holds no "
-                             "expert pool (construct DiceServer with "
-                             "paging= on an n>1 ep mesh)")
-        if pool.n_dev != n_ep:
-            raise ValueError(
-                f"expert pool is sharded for {pool.n_dev} devices but the "
-                f"serving mesh has a {n_ep}-way ep axis")
+    if pool is not None:
         pool.reset_stats()
     # observability (DESIGN.md Sec. 16): a call-scoped registry (folded
     # into server.metrics on return) replaces the local accumulator
@@ -981,9 +677,10 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     # benchmark reads back); re-sharding only triggers in "greedy" mode on
     # an n>1 ep mesh, at admission-aligned boundaries, after warmup
     pcfg = server.placement
-    n_place = n_ep
+    n_ep = (mesh.shape[ep_axis]
+            if mesh is not None and ep_axis in mesh.axis_names else 1)
     place_online = (pcfg is not None and pcfg.mode == "greedy"
-                    and n_place > 1)
+                    and n_ep > 1)
     # one tick in flight, unless a policy acts on a tick's outputs before
     # the next tick is decided: the watchdog and quarantine (resilience),
     # the online re-shard (the routing histogram), paging (its fetches)
@@ -1206,7 +903,7 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
                                  1.0 / cfg.num_experts))
             if placement_lib.drift(base, hist.shares) > pcfg.drift_threshold:
                 new_pl = placement_lib.greedy_placements(
-                    hist.shares, n_place,
+                    hist.shares, n_ep,
                     replicate_top=pcfg.replicate_top)
                 if all(p.is_identity for p in new_pl):
                     new_pl = None
@@ -1452,17 +1149,6 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
         tick += 1
     _drain()
 
-    # the latency model describes the REQUESTED deployment (server.dcfg,
-    # un-normalized) but with whatever placements the run ended on — an
-    # online re-shard changes the modeled wire volume going forward
-    lat_dcfg = server.dcfg
-    live_placements = plan_lib.placements_of(dcfg)
-    if live_placements is not None:
-        lat_dcfg = dataclasses.replace(lat_dcfg, placements=live_placements)
-    lat = modeled_step_latency(cfg, lat_dcfg, n_dev=server.n_dev,
-                               local_batch=max(1, B // server.n_dev),
-                               devices_per_host=server.devices_per_host,
-                               inter_host_bw=server.inter_host_bw)
     # max over placement epochs: each epoch's fresh step function holds
     # at most one entry per plan variant, and the peak is the contract
     # the benchmark asserts (== variants when no re-shard)
@@ -1473,8 +1159,8 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
     ticks = int(reg.value("dice_ticks_total", lab))
     padded_slot_steps = int(reg.value("dice_padded_slot_steps_total", lab))
     # the summary is a VIEW of the registry (DESIGN.md Sec. 16); the
-    # modeled-latency and placement quantities are single computed
-    # values, not accumulations, so they read straight from their source
+    # placement quantities are single computed values, not
+    # accumulations, so they read straight from their source
     stats = {
         "ticks": ticks,
         "makespan_steps": tick,
@@ -1490,14 +1176,8 @@ def serve_continuous(server: "DiceServer", requests: List[Request], *,
         "wall_s": time.perf_counter() - t0,
         "compile_s": compile_s,
         "tick_s": tick_s,
-        "modeled_step_s_tpu8": lat["t_step_s"],
-        "modeled_total_s_tpu8": lat["t_step_s"] * ticks,
-        "modeled_step_blocking_s": lat["t_step_blocking_s"],
-        "modeled_step_ring_s": lat["t_step_ring_s"],
-        "modeled_overlap_efficiency": lat["overlap_efficiency"],
         "ring_hops": int(reg.value("dice_ring_hops", lab)),
         "hop_bytes_total": reg.value("dice_hop_bytes_total", lab),
-        "a2a_bytes_per_layer": lat["a2a_bytes_layer"],
         "buffer_bytes": int(reg.value("dice_buffer_bytes", lab)),
         "dispatch_bytes_total": reg.value("dice_dispatch_bytes_total", lab),
         # wire vs raw payload flows (Sec. 11): wire == dispatch_bytes_total
@@ -1583,9 +1263,6 @@ def main():
     ap.add_argument("--no-tiny", dest="tiny", action="store_false")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--guidance", type=float, default=1.5)
-    ap.add_argument("--n-dev", type=int, default=None,
-                    help="serving mesh size for the latency model "
-                         "(default: the ep mesh size, else 8)")
     ap.add_argument("--ep", type=int, default=0,
                     help="run mesh-native over an N-way 'ep' axis "
                          "(DESIGN.md §10; needs N devices, e.g. XLA_FLAGS="
@@ -1603,12 +1280,7 @@ def main():
     ap.add_argument("--devices-per-host", type=int, default=0,
                     help="two-tier fabric: devices per host H (0 = flat). "
                          "The ring engine then orders hops intra-host "
-                         "first (topology-aware schedule, §14) and the "
-                         "latency model prices host-crossing hops at "
-                         "--inter-host-bw")
-    ap.add_argument("--inter-host-bw", type=float, default=0.2e9,
-                    help="effective inter-host trunk bandwidth B/s for "
-                         "the two-tier latency model (default 0.2 GB/s)")
+                         "first (topology-aware schedule, §14)")
     ap.add_argument("--codec", choices=list(CODEC_KINDS), default="none",
                     help="wire codec for staleness-era payloads (Sec. 11): "
                          "light/stale steps transmit quantized residuals "
@@ -1622,8 +1294,7 @@ def main():
                     help="a2a execution engine (DESIGN.md Sec. 12): "
                          "'ring' pipelines (n-1) chunked ppermute hops "
                          "against the expert FFN instead of two blocking "
-                         "all-to-alls (executed when --ep > 1; always "
-                         "reflected in the modeled latency)")
+                         "all-to-alls (executed when --ep > 1)")
     ap.add_argument("--placement", choices=["identity", "greedy"],
                     default="identity",
                     help="expert placement policy (DESIGN.md Sec. 13): "
@@ -1638,7 +1309,7 @@ def main():
                     help="expert paging (DESIGN.md Sec. 15): hold the full "
                          "expert set in host RAM and page per-layer shards "
                          "into device memory one MoE layer ahead of use "
-                         "(needs --ep > 1; also lifts the E %% n_dev == 0 "
+                         "(needs --ep > 1; also lifts the E %% ep == 0 "
                          "restriction via phantom-expert padding)")
     ap.add_argument("--expert-hbm-budget", type=int, default=0,
                     help="per-device byte budget for resident routed-expert "
@@ -1679,13 +1350,17 @@ def main():
     enable_compile_cache()
 
     cfg = tiny() if args.tiny else xl_config()
-    dcfg = SCHEDULES[args.schedule]()
     paging = None
     if args.paging == "on":
         paging = paging_lib.PagingSpec(
             budget_bytes=(None if args.expert_hbm_budget < 0
                           else args.expert_hbm_budget),
             depth=args.paging_depth)
+    resilience = fault_lib.parse_resilience(args.faults)
+    dcfg = dataclasses.replace(
+        SCHEDULES[args.schedule](),
+        compress=CompressConfig(codec=args.codec, topk_frac=args.topk_frac),
+        overlap=args.overlap, paging=paging, resilience=resilience)
     params = None
     expert_pool = None
     if args.ckpt:
@@ -1697,7 +1372,7 @@ def main():
             # expert stacks land in the host pool, the rest in the device
             # tree — the full param tree is never materialized at once
             params, expert_pool = paging_lib.load_pooled_checkpoint(
-                args.ckpt, like, n_dev=args.ep)
+                args.ckpt, like, args.ep)
         else:
             params = load_checkpoint(args.ckpt, like)
     mesh = None
@@ -1705,21 +1380,13 @@ def main():
         from repro.launch.mesh import make_mesh
         mesh = make_mesh(ep=max(1, args.ep), dp=args.dp, patch=args.patch)
     obs_on = bool(args.obs or args.trace_out or args.metrics_out)
-    resilience = fault_lib.parse_resilience(args.faults)
-    server = DiceServer(cfg, dcfg, params=params, n_dev=args.n_dev,
-                        mesh=mesh,
-                        compress=CompressConfig(codec=args.codec,
-                                                topk_frac=args.topk_frac),
-                        overlap=args.overlap,
+    server = DiceServer(cfg, dcfg, params=params, mesh=mesh,
                         placement=placement_lib.PlacementConfig(
                             mode=args.placement,
                             replicate_top=args.replicate_top),
-                        paging=paging,
                         expert_pool=expert_pool,
                         devices_per_host=args.devices_per_host,
-                        inter_host_bw=args.inter_host_bw,
-                        obs=ObsConfig(enabled=obs_on),
-                        resilience=resilience)
+                        obs=ObsConfig(enabled=obs_on))
     reqs = [Request(class_id=i % cfg.num_classes, rid=i)
             for i in range(args.requests)]
     splan = server.plan(args.steps)
@@ -1728,10 +1395,11 @@ def main():
         mesh_tag = ", mesh-native " + " x ".join(
             f"{mesh.shape[a]}-way {a}" for a in mesh.axis_names)
     print(f"serving {len(reqs)} requests, schedule={args.schedule}, "
-          f"{args.steps} steps, model={cfg.name}, n_dev={server.n_dev}"
+          f"{args.steps} steps, model={cfg.name}"
           + mesh_tag
-          + (f", wire codec {args.codec}" if args.codec != "none" else "")
-          + (", ring overlap" if args.overlap == "ring" else "")
+          + (f", wire codec {args.codec}"
+             if plan_lib.codec_spec_of(server.dcfg) is not None else "")
+          + (", ring overlap" if plan_lib.overlap_of(server.dcfg) else "")
           + (f", paging on (pool {server.expert_pool.num_experts}->"
              f"{server.expert_pool.num_wire_experts} experts, budget "
              f"{paging_lib.paging_of(server.dcfg).budget_bytes} B/dev)"

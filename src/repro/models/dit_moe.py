@@ -171,7 +171,7 @@ def dit_forward(params, x, t, y, cfg: ModelConfig, dcfg: DiceConfig,
     if paged and expert_pool is None:
         raise ValueError("the plan carries expert paging but no expert_pool "
                          "was provided (pass repro.core.paging.ExpertPool, "
-                         "or normalize the config with normalize_paging)")
+                         "or resolve the config with repro.core.plan.resolve)")
     if paged and ep_axis is None:
         raise ValueError("expert paging needs a live ep mesh axis")
     fetched: Dict[int, Dict[str, Any]] = {}

@@ -111,7 +111,7 @@ def resilience_of(dcfg) -> Optional[ResilienceConfig]:
 def normalize_resilience(
         res: Optional[ResilienceConfig]) -> Optional[ResilienceConfig]:
     """Strip inert configs so "resilience off" is structurally ``None``
-    (byte-identical graphs), mirroring ``normalize_paging``."""
+    (byte-identical graphs); ``repro.core.plan.resolve`` applies it."""
     if res is None:
         return None
     if res.faults is not None and not res.faults.enabled:

@@ -279,12 +279,8 @@ def _make_mesh_rf_step(params, cfg: ModelConfig, dcfg: DiceConfig, *,
         # the pool owns the routed-expert stacks (host RAM); the device
         # tree keeps only the always-resident remainder — router, shared
         # experts, attention, embeddings (DESIGN.md Sec. 15)
-        if expert_pool is None and paging_lib.has_expert_leaves(params):
-            expert_pool = paging_lib.pool_from_params(params, n_dev=n_ep)
-        if expert_pool is None:
-            raise ValueError("paging is planned but params carry no expert "
-                             "leaves and no expert_pool was provided")
-        params = paging_lib.strip_expert_params(params)
+        params, expert_pool = paging_lib.split_params(params, expert_pool,
+                                                      n_ep)
     pool = expert_pool if paged else None
     params = shard_lib.ep_shard_params(params, mesh, ep_axis=live_ep)
     pspecs = shard_lib.ep_param_specs(params, ep_axis=live_ep)
@@ -455,17 +451,10 @@ def rf_sample(params, cfg: ModelConfig, dcfg: DiceConfig, *,
             else 1)
     patch_axis = ("patch" if mesh is not None
                   and "patch" in mesh.axis_names else None)
-    # ring overlap is an n>1-mesh execution property: normalize it away
-    # here so a mesh-less (or 1-device-axis) run plans — and therefore
-    # samples — bit-identically to a blocking config (DESIGN.md Sec. 12)
-    dcfg = plan_lib.normalize_overlap(dcfg, n_ep)
-    # likewise placement: on a single device the params are unpermuted, so
-    # a placement-bearing config must fall back to the identity layout to
-    # stay bit-identical with its mesh-less baseline (DESIGN.md Sec. 13)
-    dcfg = plan_lib.normalize_placement(dcfg, n_ep)
-    # and paging: mesh-less runs keep their expert stacks in the params
-    # tree and plan exactly like fully-resident configs (DESIGN.md Sec. 15)
-    dcfg = plan_lib.normalize_paging(dcfg, n_ep)
+    # the config as this mesh runs it: a mesh-less (or 1-device-axis) run
+    # drops ring overlap, placements and paging, and so plans — and
+    # samples — bit-identically to the plain config (plan.resolve)
+    dcfg = plan_lib.resolve(dcfg, n_ep)
     if paging_lib.paging_of(dcfg) is not None:
         if expert_pool is None:
             expert_pool = paging_lib.pool_from_params(params, n_dev=n_ep)

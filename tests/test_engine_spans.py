@@ -13,6 +13,7 @@ args, so those are pinned here too.  Every span is also a
 host events.  The model's layer parts carry ``jax.named_scope`` names
 in their HLO metadata whatever the observability setting.
 """
+import dataclasses
 import glob
 import os
 
@@ -48,8 +49,8 @@ def small_cfg():
 
 def _serve(tracer=None, resilience=None, n=len(ARRIVALS)):
     cfg = small_cfg()
-    server = DiceServer(cfg, DiceConfig.dice(), seed=0,
-                        resilience=resilience)
+    server = DiceServer(cfg, dataclasses.replace(
+        DiceConfig.dice(), resilience=resilience), seed=0)
     server.tracer = tracer
     reqs = [Request(class_id=i % cfg.num_classes, rid=i) for i in range(n)]
     return serve_continuous(server, reqs, max_batch=B, num_steps=NUM_STEPS,

@@ -19,6 +19,7 @@ The load-bearing guarantees tested here:
   shed with a retry-after hint, under arrival floods and on an 8-device
   mesh under a multi-fault storm (subprocess chaos case below).
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -109,7 +110,7 @@ def test_parse_resilience_spec():
 def test_normalize_strips_inert_configs():
     assert normalize_resilience(None) is None
     # all-zero fault rates are structurally OFF -> None (byte-identical
-    # graphs, same discipline as normalize_paging)
+    # graphs, the discipline plan.resolve applies to every feature)
     off = ResilienceConfig(faults=FaultConfig(), guards=False,
                            quarantine=False)
     assert normalize_resilience(off) is None
@@ -295,8 +296,8 @@ def _dice_int8():
 
 def _serve(cfg, params, dcfg, *, resilience=None, obs=False, nreq=3,
            max_batch=2, num_steps=4, arrivals=None):
-    server = DiceServer(cfg, dcfg, params=params, resilience=resilience,
-                        obs=ObsConfig(enabled=obs))
+    server = DiceServer(cfg, dataclasses.replace(dcfg, resilience=resilience),
+                        params=params, obs=ObsConfig(enabled=obs))
     reqs = [Request(class_id=i % cfg.num_classes, rid=i)
             for i in range(nreq)]
     return serve_continuous(
@@ -392,6 +393,7 @@ def test_chaos_eight_device_all_schedules():
     visible degradation events, and hop-delay injection active while the
     ring engine is live."""
     prog = textwrap.dedent("""
+        import dataclasses
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np
@@ -419,8 +421,9 @@ def test_chaos_eight_device_all_schedules():
         reqs = [Request(class_id=i % cfg.num_classes, rid=i)
                 for i in range(10)]
         for name, dcfg in scheds.items():
-            server = DiceServer(cfg, dcfg, params=params, mesh=mesh,
-                                resilience=res)
+            server = DiceServer(cfg,
+                                dataclasses.replace(dcfg, resilience=res),
+                                params=params, mesh=mesh)
             out, stats = serve_continuous(
                 server, reqs, max_batch=8, num_steps=4,
                 key=jax.random.PRNGKey(7),
@@ -442,8 +445,9 @@ def test_chaos_eight_device_all_schedules():
         # ring engine + seeded slow hops: injection is live only while the
         # ring is (demotion would stop it) and the run still completes
         ring = parse_resilience("seed=3,corrupt=0.05,hop_delay=0.5:0.001")
-        server = DiceServer(cfg, DiceConfig.dice(overlap="ring"),
-                            params=params, mesh=mesh, resilience=ring)
+        server = DiceServer(cfg, dataclasses.replace(
+            DiceConfig.dice(overlap="ring"), resilience=ring),
+            params=params, mesh=mesh)
         out, stats = serve_continuous(
             server, reqs[:8], max_batch=8, num_steps=4,
             key=jax.random.PRNGKey(7), arrival_steps=[0.0] * 8)

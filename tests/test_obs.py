@@ -351,10 +351,8 @@ def test_serve_queue_summary_is_registry_view():
     assert stats["padded"] == 1          # 3 requests -> batches of 2
     assert stats["jit_cache_size"] == stats["num_plan_variants"]
     # legacy summary keys survive the registry-view rewrite
-    for k in ("modeled_step_s_tpu8", "modeled_total_s_tpu8",
-              "a2a_bytes_per_layer", "buffer_bytes", "dispatch_bytes_total",
-              "wire_bytes_total", "raw_bytes_total", "ring_hops",
-              "hop_bytes_total", "modeled_overlap_efficiency"):
+    for k in ("buffer_bytes", "dispatch_bytes_total", "wire_bytes_total",
+              "raw_bytes_total", "ring_hops", "hop_bytes_total"):
         assert k in stats, k
     # the per-call registry folded into the server's source of truth
     lab = {"schedule": "sync", "engine": "queue"}

@@ -247,16 +247,16 @@ def test_normalize_overlap_and_plan_stamping():
 
     ring = DiceConfig.dice(overlap="ring")
     # stripped on one device, kept on many
-    assert plan_lib.normalize_overlap(ring, 1) == DiceConfig.dice()
-    assert plan_lib.normalize_overlap(ring, 8) is ring
-    assert plan_lib.normalize_overlap(DiceConfig.dice(), 1) \
+    assert plan_lib.resolve(ring, 1) == DiceConfig.dice()
+    assert plan_lib.resolve(ring, 8) is ring
+    assert plan_lib.resolve(DiceConfig.dice(), 1) \
         == DiceConfig.dice()
     # the planner stamps every action; variant structure is unchanged
     for dcfg in (ring, DiceConfig.sync_ep(overlap="ring"),
                  DiceConfig.staggered_batch(overlap="ring")):
         splan = plan_lib.compile_step_plans(dcfg, 4, 6, experts_per_token=2)
         base = plan_lib.compile_step_plans(
-            plan_lib.normalize_overlap(dcfg, 1), 4, 6, experts_per_token=2)
+            plan_lib.resolve(dcfg, 1), 4, 6, experts_per_token=2)
         assert splan.num_variants == base.num_variants
         assert all(a.overlap for p in splan.steps for a in p.actions)
         assert not any(a.overlap for p in base.steps for a in p.actions)
@@ -275,7 +275,7 @@ def test_modeled_ring_latency_pipeline_bound():
     ring bound equals t_local + (n-1)*max(t_hop_comm, t_hop_comp)."""
     from repro.configs.dit_moe_xl import config as xl_config
     from repro.core.schedules import DiceConfig
-    from repro.launch.serve import modeled_step_latency
+    from repro.metrics.latency_model import modeled_step_latency
 
     cfg = xl_config()
     for n_dev in (2, 4, 8):

@@ -173,7 +173,7 @@ def test_plan_stamps_prefetch_and_resident():
 def test_normalize_paging_strips_meshless_plans_bit_identical():
     base = DiceConfig.dice()
     paged = dataclasses.replace(base, paging=PagingSpec(budget_bytes=None))
-    norm = paging_lib.normalize_paging(paged, 1)
+    norm = plan_lib.resolve(paged, 1)
     for step in range(4):
         ref = plan_lib.plan_for_step(base, 4, step,
                                      experts_per_token=2)
@@ -181,7 +181,7 @@ def test_normalize_paging_strips_meshless_plans_bit_identical():
                                      experts_per_token=2)
         assert got == ref                     # same hash -> same jit entry
     # n > 1 keeps the spec
-    assert paging_lib.paging_of(paging_lib.normalize_paging(paged, 8))
+    assert paging_lib.paging_of(plan_lib.resolve(paged, 8))
 
 
 def test_paging_excludes_placement():

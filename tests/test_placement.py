@@ -309,8 +309,8 @@ def test_normalize_placement_strips_single_device():
                    cap_scale=0.5)
     dcfg = dataclasses.replace(DiceConfig.sync_ep(), placements=(pl, pl))
     assert plan_lib.placements_of(
-        plan_lib.normalize_placement(dcfg, 1)) is None
-    assert plan_lib.normalize_placement(dcfg, 8) is dcfg
+        plan_lib.resolve(dcfg, 1)) is None
+    assert plan_lib.resolve(dcfg, 8) is dcfg
     # wire-scale model: mean cap_scale over layers
     assert plan_lib.placement_wire_scale(dcfg) == 0.5
     assert plan_lib.placement_wire_scale(DiceConfig.sync_ep()) == 1.0

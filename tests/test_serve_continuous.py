@@ -11,6 +11,8 @@ independent once nothing overflows capacity; the test config pins
 ``capacity_factor = num_experts`` so overflow is impossible by
 construction.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -225,7 +227,7 @@ def test_paper_comm_fraction_band():
     the Table-5-calibrated all-to-all share must still land in the paper's
     60-80% band on the 4090-PCIe hardware point."""
     from repro.configs.dit_moe_xl import config as xl_config
-    from repro.launch.serve import PAPER_HW, layer_compute_flops
+    from repro.metrics.latency_model import PAPER_HW, layer_compute_flops
     cfg = xl_config()
     for n_dev in (4, 8):
         for b in (4, 8, 16, 32):
@@ -240,7 +242,8 @@ def test_paper_comm_fraction_band():
 
 def test_sharded_recycled_slots_match_fresh_sharded_batch():
     """Mesh-native continuous batching (DESIGN.md §10): requests admitted
-    into RECYCLED slots of an 8-way-ep ``serve_continuous(mesh=...)`` run
+    into RECYCLED slots of an 8-way-ep mesh server's ``serve_continuous``
+    run
     must be bit-identical to the same requests in a fresh sharded batch —
     no cross-request leakage through the sharded ``h_cache``/``y_buf``
     rows — and the jit cache must stay at the plan-variant count.
@@ -420,8 +423,8 @@ def test_resilience_keeps_no_tick_in_flight(setup):
     dcfg = DiceConfig.dice()
     reqs = _three_cohorts(cfg)
     key = jax.random.PRNGKey(11)
-    server = DiceServer(cfg, dcfg, params=params,
-                        resilience=ResilienceConfig(guards=True))
+    server = DiceServer(cfg, dataclasses.replace(
+        dcfg, resilience=ResilienceConfig(guards=True)), params=params)
     out, stats = serve_continuous(server, reqs, max_batch=2, num_steps=4,
                                   key=key)
     assert stats["pipelined_ticks"] == 0
@@ -429,6 +432,65 @@ def test_resilience_keeps_no_tick_in_flight(setup):
     assert server.metrics.get("dice_pipelined_ticks_total", lab) is None
     ref, _ = serve_continuous(DiceServer(cfg, dcfg, params=params), reqs,
                               max_batch=2, num_steps=4, key=key)
+    assert sorted(out) == sorted(ref)
+    for rid in ref:
+        np.testing.assert_array_equal(out[rid], ref[rid])
+
+
+def _ring():
+    return DiceConfig.dice(overlap="ring")
+
+
+def _placed():
+    from repro.core.placement import Placement
+    pl = Placement(perm=(1, 0, 2, 3, 4, 5, 6, 7), replicated=(0,),
+                   cap_scale=0.5)
+    return dataclasses.replace(DiceConfig.dice(), placements=(pl,) * 4)
+
+
+def _paged():
+    from repro.core.paging import PagingSpec
+    return dataclasses.replace(DiceConfig.dice(),
+                               paging=PagingSpec(budget_bytes=None))
+
+
+def _codec_none():
+    from repro.compress.codecs import CompressConfig
+    return DiceConfig.dice(compress=CompressConfig(codec="none"))
+
+
+def _resilience_off():
+    from repro.resilience.faults import ResilienceConfig
+    return dataclasses.replace(DiceConfig.dice(), resilience=ResilienceConfig(
+        guards=False, quarantine=False))
+
+
+@pytest.fixture(scope="module")
+def plain_dice_run(setup):
+    cfg, params = setup
+    return serve_continuous(DiceServer(cfg, DiceConfig.dice(), params=params),
+                            _three_cohorts(cfg), max_batch=2, num_steps=4,
+                            key=jax.random.PRNGKey(5))
+
+
+@pytest.mark.parametrize("make", [_ring, _placed, _paged, _codec_none,
+                                  _resilience_off])
+def test_meshless_server_resolves_its_config_once(make, setup,
+                                                  plain_dice_run):
+    """A mesh-less server stores its config resolved against a 1-way ep
+    axis (``plan.resolve``), and ``serve_continuous`` runs it as stored:
+    ring overlap, placements, paging, codec "none" and inert resilience
+    all serve bit-identically to a plain ``DiceConfig.dice()`` server,
+    with one jit entry per plan variant."""
+    cfg, params = setup
+    raw = make()
+    server = DiceServer(cfg, raw, params=params)
+    assert server.dcfg == plan_lib.resolve(raw, 1) == DiceConfig.dice()
+    out, stats = serve_continuous(server, _three_cohorts(cfg), max_batch=2,
+                                  num_steps=4, key=jax.random.PRNGKey(5))
+    ref, ref_stats = plain_dice_run
+    assert stats["jit_cache_size"] == stats["num_plan_variants"] \
+        == ref_stats["num_plan_variants"]
     assert sorted(out) == sorted(ref)
     for rid in ref:
         np.testing.assert_array_equal(out[rid], ref[rid])
@@ -445,6 +507,7 @@ def test_placement_and_paging_keep_no_tick_in_flight():
     import sys
     import textwrap
     prog = textwrap.dedent("""
+        import dataclasses
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
@@ -465,9 +528,8 @@ def test_placement_and_paging_keep_no_tick_in_flight():
                 for i in range(24)]
         key = jax.random.PRNGKey(11)
 
-        def run(**kw):
-            server = DiceServer(cfg, DiceConfig.dice(), params=params,
-                                mesh=mesh, **kw)
+        def run(dcfg=DiceConfig.dice(), **kw):
+            server = DiceServer(cfg, dcfg, params=params, mesh=mesh, **kw)
             return serve_continuous(server, reqs, max_batch=8, num_steps=4,
                                     key=key)
 
@@ -482,7 +544,8 @@ def test_placement_and_paging_keep_no_tick_in_flight():
         assert sorted(placed) == sorted(out) == list(range(24))
         for rid in out:
             np.testing.assert_array_equal(placed[rid], out[rid])
-        paged, gstats = run(paging=PagingSpec(budget_bytes=None, depth=1))
+        paged, gstats = run(dataclasses.replace(
+            DiceConfig.dice(), paging=PagingSpec(budget_bytes=None, depth=1)))
         assert gstats["pipelined_ticks"] == 0, gstats
         assert gstats["paged_transfers"] > 0, gstats
         assert sorted(paged) == list(range(24))

@@ -55,7 +55,7 @@ def test_staggered_double_buffers():
 
 def test_staggered_modeled_latency_slower_than_interweaved():
     from repro.configs.dit_moe_xl import config
-    from repro.launch.serve import modeled_step_latency
+    from repro.metrics.latency_model import modeled_step_latency
     cfg = config()
     t_int = modeled_step_latency(cfg, DiceConfig.interweaved(),
                                  local_batch=8)["t_step_s"]

@@ -119,7 +119,7 @@ def test_serve_queue_drains_requests(trained):
         assert np.isfinite(np.asarray(s)).all()
     # byte/compile stats survive aggregation (consumed by the throughput
     # benchmark): sums for byte flows, max for sizes and the jit cache
-    assert stats["a2a_bytes_per_layer"] > 0
     assert stats["buffer_bytes"] > 0
     assert stats["dispatch_bytes_total"] > 0
+    assert stats["raw_bytes_total"] == stats["dispatch_bytes_total"]
     assert stats["jit_cache_size"] == stats["num_plan_variants"] > 0
